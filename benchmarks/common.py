@@ -37,10 +37,11 @@ def emit(name: str, seconds: float, derived: str = ""):
 
 def run_with_devices(code: str, n_devices: int, timeout: int = 1200) -> str:
     env = dict(os.environ)
+    # Virtual host devices: the child runs on the CPU, never on the chip
+    # this parent may hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    # Toolchain gates first: snippets use jax.shard_map / AxisType directly.
-    code = "import repro  # noqa: F401 (jax API compat shims)\n" + code
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=timeout, env=env)
     if proc.returncode != 0:

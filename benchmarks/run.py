@@ -235,6 +235,9 @@ def main() -> None:
                     help="relative qps/p99 slack for --compare "
                          "(default 0.25)")
     args = ap.parse_args()
+    from repro.compile_cache import configure
+
+    configure()
     which = args.suites or list(SUITES)
     print("name,us_per_call,derived")
     t0 = time.time()

@@ -1,7 +1,1 @@
-"""repro — multi-device exact kNN (arXiv:0906.0231) grown into a serving system.
-
-Importing any ``repro`` module first applies the toolchain gates in
-``repro._compat`` (the pinned container jax predates a few API renames the
-code targets; see that module's docstring).
-"""
-from repro import _compat as _compat  # noqa: F401  (side-effect import)
+"""repro — multi-device exact kNN (arXiv:0906.0231) grown into a serving system."""

@@ -59,12 +59,10 @@ Array = jnp.ndarray
 # ---------------------------------------------------------------------------
 
 
-def _pvary(x, axis_name):
+def _varying(x, axis_name):
     """Mark a device-invariant value as varying over ``axis_name`` (vma)."""
     names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, names)
-    return jax.lax.pcast(x, names, to="varying")  # pragma: no cover
+    return jax.lax.pcast(x, names, to="varying")
 
 
 def tree_merge_topk(run_v: Array, run_i: Array, axis_name,
@@ -164,6 +162,11 @@ def ring_allpairs_shard(
     ``x_local``: this device's row block [n_loc, d] (zero-padded rows beyond
     ``n_real`` globally).  Returns this block's ascending (values, indices)
     [n_loc, K].  Runs inside shard_map.
+
+    ``impl="fused"`` never builds the [n_loc, n_loc] tile (6.4 GB at the
+    paper's n = 160k over 4 devices): each block pair runs the fused kernel
+    twice, rows against the visiting block and the visiting block against
+    the rows, and merges the two top-K sets.
     """
     threshold_skip = T.resolve_threshold_skip(threshold_skip, pallas=False)
     dist = get_distance(distance)
@@ -182,13 +185,28 @@ def ring_allpairs_shard(
             tile = jnp.where(row_ids == col_ids, T.POS_INF, tile)
         return tile
 
+    def fused_block(rows, cols, col_block, exclude_diag):
+        # K smallest of rows vs one column block; columns past n_real are
+        # dead (rows past it are padding, sliced off by the caller).
+        from repro.kernels import ops as kops
+
+        res = kops.fused_knn(rows, cols, k, distance=distance,
+                             exclude_self=exclude_diag,
+                             db_valid=n_real - col_block * n_loc,
+                             threshold_skip=threshold_skip)
+        v, i = T.pad_topk(res.distances, res.indices, K)
+        return v, jnp.where(jnp.isfinite(v), i + col_block * n_loc, -1)
+
     # Diagonal tile: own vs own, self-excluded. No communication.
-    run_v, run_i = T.init_running(n_loc, k)
-    tile = _local_tile(x_local, x_local, dist, impl)
-    tile = masked(tile, p, p, True)
-    run_v, run_i = T.update_running(
-        run_v, run_i, tile, p * n_loc, threshold_skip=threshold_skip
-    )
+    if impl == "fused":
+        run_v, run_i = fused_block(x_local, x_local, p, True)
+    else:
+        run_v, run_i = T.init_running(n_loc, k)
+        tile = _local_tile(x_local, x_local, dist, impl)
+        tile = masked(tile, p, p, True)
+        run_v, run_i = T.update_running(
+            run_v, run_i, tile, p * n_loc, threshold_skip=threshold_skip
+        )
 
     if P == 1:
         return run_v, run_i
@@ -207,8 +225,8 @@ def ring_allpairs_shard(
     vis_v, vis_i = T.init_running(n_loc, k)
     if wd is not None:
         vis_v = vis_v.astype(wd)
-    vis_v = _pvary(vis_v, axis_name)
-    vis_i = _pvary(vis_i, axis_name)
+    vis_v = _varying(vis_v, axis_name)
+    vis_i = _varying(vis_i, axis_name)
 
     rot = _rotate if wd is None else _rotate_bits
 
@@ -220,21 +238,30 @@ def ring_allpairs_shard(
         vis_v = rot(vis_v, axis_name, 1)
         vis_i = _rotate(vis_i, axis_name, 1)
         src = jax.lax.rem(p - s + P, P)  # owner of the visiting block
-
-        tile = _local_tile(x_local, vis_block.astype(x_local.dtype), dist, impl)
-        tile = masked(tile, p, src, False)
         # Even-P final half-step: each unordered pair {p, p+P/2} would be seen
         # twice; only the lower device keeps it (the paper's "virtual mirror").
+        active = True
         if sym and P % 2 == 0:
             last = s == n_steps
             active = jnp.logical_or(jnp.logical_not(last), p < P // 2)
-            tile = jnp.where(active, tile, T.POS_INF)
 
-        run_v, run_i = T.update_running(
-            run_v, run_i, tile, src * n_loc, threshold_skip=threshold_skip
-        )
+        cols = vis_block.astype(x_local.dtype)
+        if impl == "fused":
+            tv, ti = fused_block(x_local, cols, src, False)
+            tv = jnp.where(active, tv, T.POS_INF)
+            run_v, run_i = T.merge_topk_sorted(run_v, run_i, tv, ti)
+            if sym:
+                tv, ti = fused_block(cols, x_local, p, False)
+                tv = jnp.where(active, tv, T.POS_INF)
+        else:
+            tile = _local_tile(x_local, cols, dist, impl)
+            tile = jnp.where(active, masked(tile, p, src, False), T.POS_INF)
+            run_v, run_i = T.update_running(
+                run_v, run_i, tile, src * n_loc, threshold_skip=threshold_skip
+            )
+            if sym:
+                tv, ti = T.tile_topk(tile.T, K, p * n_loc)
         if sym:
-            tv, ti = T.tile_topk(tile.T, T.next_pow2(k), p * n_loc)
             mv, mi = T.merge_topk_sorted(vis_v.astype(jnp.float32), vis_i, tv, ti)
             vis_v = mv if wd is None else mv.astype(wd)
             vis_i = mi
@@ -294,8 +321,8 @@ def triangle_allpairs_shard(
     x = jax.lax.all_gather(x_local, axis_name, tiled=True)
     n_pad, d = x.shape
     K = T.next_pow2(k)
-    run_v = _pvary(jnp.full((n_pad, K), T.POS_INF, jnp.float32), axis_name)
-    run_i = _pvary(jnp.full((n_pad, K), -1, jnp.int32), axis_name)
+    run_v = _varying(jnp.full((n_pad, K), T.POS_INF, jnp.float32), axis_name)
+    run_i = _varying(jnp.full((n_pad, K), -1, jnp.int32), axis_name)
 
     def masked(tile, row_off, col_off):
         row_ids = row_off + jnp.arange(gsize)[:, None]
@@ -563,20 +590,52 @@ def ivfpq_query_sharded_shard(
     *,
     db_axis,
     k: int,
-    nprobe: int,
-    cell_cap: int,
-    distance: str = "sqeuclidean",
-    impl: str = "fused",
-    overfetch: int = 4,
     wire_dtype=None,
-    threshold_skip: bool | None = None,
-    residual: bool = True,
+    **kw,
 ) -> tuple[Array, Array]:
     """IVF-PQ serving path: codebooks replicated, code blocks row-sharded.
 
     The same shard contract as ``ivf_query_sharded_shard`` (DESIGN.md §PQ):
     ``ncells % P == 0`` cells shard contiguously over ``db_axis``, and each
-    shard runs the full pipeline locally before the butterfly merge —
+    shard runs the full pipeline locally (``ivfpq_shard_candidates``) before
+    the butterfly merge.  The butterfly payload stays K exact (value, GLOBAL
+    corpus row) pairs per query row, optionally on the bf16 wire — the
+    n-scaling arrays a shard touches per query are m-byte code rows, which
+    is what makes million-row mains servable from HBM (ROADMAP north star).
+    """
+    vals, idx = ivfpq_shard_candidates(
+        q_local, centroids, pq_cb, pq_codes_local, packed_local,
+        row_of_slot_local, live_packed_local,
+        shard=jax.lax.axis_index(db_axis),
+        n_shards=jax.lax.axis_size(db_axis), k=k, **kw)
+    vals, idx = tree_merge_topk(vals, idx, db_axis, wire_dtype=wire_dtype)
+    return vals[:, :k], idx[:, :k]
+
+
+def ivfpq_shard_candidates(
+    q_local: Array,
+    centroids: Array,
+    pq_cb,
+    pq_codes_local,
+    packed_local: Array,
+    row_of_slot_local: Array,
+    live_packed_local: Array | None = None,
+    *,
+    shard,
+    n_shards: int,
+    k: int,
+    nprobe: int,
+    cell_cap: int,
+    distance: str = "sqeuclidean",
+    impl: str = "fused",
+    overfetch: int = 4,
+    threshold_skip: bool | None = None,
+    residual: bool = True,
+) -> tuple[Array, Array]:
+    """One IVF-PQ shard's exact top-K candidates [m, K] (GLOBAL corpus rows).
+
+    Shard ``shard`` of ``n_shards`` owns the contiguous cells
+    [shard·ncells/P, (shard+1)·ncells/P):
 
       1. the GLOBAL centroid shortlist (centroids and the PQ codebook are
          replicated: the shortlist is tiny, the codebook is m·2^nbits·d/m·4
@@ -589,22 +648,19 @@ def ivfpq_query_sharded_shard(
       3. exact local rescore against the fp32 packed slice, candidates
          externalized through the local ``row_of_slot`` slice.
 
-    The butterfly payload stays K exact (value, GLOBAL corpus row) pairs per
-    query row, optionally on the bf16 wire — the n-scaling arrays a shard
-    touches per query are m-byte code rows, which is what makes million-row
-    mains servable from HBM (ROADMAP north star).
+    Free of collectives, so one device can run every shard in turn and
+    merge, which is the single-chip twin of the sharded path.
     """
     from repro.core import ivf as IVF
     from repro.core.knn import quantized_scan as q_scan
     from repro.core.pq import pq_cell_bias
     from repro.kernels._backend import resolve_interpret
 
-    P = jax.lax.axis_size(db_axis)
-    p = jax.lax.axis_index(db_axis)
+    p = shard
     S_loc = packed_local.shape[0]
     assert S_loc % cell_cap == 0, (S_loc, cell_cap)
     ncells_loc = S_loc // cell_cap
-    ncells = ncells_loc * P
+    ncells = ncells_loc * n_shards
     d = q_local.shape[1]
     K = T.next_pow2(k)
     k_loc = min(k, S_loc)
@@ -657,8 +713,7 @@ def ivfpq_query_sharded_shard(
     idx = jnp.where(idx >= 0, jnp.take(row_of_slot_local, safe), -1)
     if vals.shape[1] < K:
         vals, idx = T.pad_topk(vals, idx, K)
-    vals, idx = tree_merge_topk(vals, idx, db_axis, wire_dtype=wire_dtype)
-    return vals[:, :k], idx[:, :k]
+    return vals, idx
 
 
 # ---------------------------------------------------------------------------

@@ -226,12 +226,14 @@ def knn_allpairs(
     ``symmetric=True`` computes only upper-triangle grids and pushes each tile
     into both its row heaps and (transposed) its column heaps — exactly the
     paper's Fig. 5 with one device.  ``symmetric=False`` falls back to the
-    full-square ``knn_query(x, x)`` (the non-symmetric-delta variant).
+    full-square ``knn_query(x, x)`` (the non-symmetric-delta variant), and
+    so does ``impl="fused"``: the fused kernel keeps no column heaps, so it
+    scores the full square in one kernel.
     """
     dist = get_distance(distance)
     from repro.core.distances import is_symmetric
 
-    if not symmetric or not is_symmetric(distance):
+    if impl == "fused" or not symmetric or not is_symmetric(distance):
         return knn_query(
             x,
             x,

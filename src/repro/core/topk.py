@@ -10,8 +10,8 @@ selection network* with completely static dataflow:
   (K = next_pow2(k)), the k-th smallest readable at position k-1 in O(1),
   exactly the property the paper wants from its descending heap;
 * a candidate tile is reduced with a bitonic sorting network (log^2 K
-  compare-exchange stages, all expressible as reshape/flip/min/max — no
-  gathers, no data-dependent control flow);
+  compare-exchange stages, each two lane rotations and a select — no
+  gathers, no reshapes of the lane axis, no data-dependent control flow);
 * two sorted K-buffers are merged with the classic bitonic *top-k merge*:
   elementwise min(a_i, b_rev_i) holds exactly the K smallest of the union and
   is bitonic, so one log-K merge network re-sorts it;
@@ -29,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jnp.ndarray
 
@@ -60,74 +61,109 @@ def resolve_threshold_skip(flag: bool | None, *, pallas: bool) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Bitonic compare-exchange stage via reshape/flip (partner index = i XOR j).
+# Bitonic network on the lane (last) axis.  The Pallas TPU lowering refuses
+# `rev` and any reshape that splits or merges the lane axis, so a partner
+# exchange (lane i <-> lane i XOR j) is two lane rotations and a select, and
+# the K-wide groups of a tile sit side by side on the lane axis.  The same
+# code runs on the XLA paths and inside the kernels.
 # ---------------------------------------------------------------------------
+
+LANES = 128  # lane width of a TPU vector register
+
+
+def _lane_pos(x: Array) -> Array:
+    """Lane index, shaped to broadcast against ``x``."""
+    shape = (1,) * (x.ndim - 1) + (x.shape[-1],)
+    return jax.lax.broadcasted_iota(jnp.int32, shape, x.ndim - 1)
+
+
+# ``pltpu.roll`` is XLA's roll off the TPU and a native lane rotation in a
+# kernel (``jnp.roll`` on int32 lanes aborts the TPU compiler).  The
+# primitive has no eager rule, hence the jit.
+_roll = jax.jit(pltpu.roll, static_argnums=(1, 2))
 
 
 def _partner(x: Array, j: int) -> Array:
-    """Value at index (i XOR j) along the last axis, as reshape+flip (no gather)."""
-    L = x.shape[-1]
-    xr = x.reshape(*x.shape[:-1], L // (2 * j), 2, j)
-    return jnp.flip(xr, axis=-2).reshape(x.shape)
+    """``x`` at lane ``i XOR j``: one rotation by j, one by L - j, a select.
 
-
-def _stage(vals: Array, idx: Array, j: int, up: Array):
-    """One compare-exchange stage of the bitonic network.
-
-    ``up`` is a static bool vector over the last axis: True where the enclosing
-    block sorts ascending.  Ties broken by original position so that value/index
-    pairs stay consistent between the two halves of each pair.
+    Which of the two rotations brings lane ``i XOR j`` is read off a rotated
+    lane iota, so the select holds whichever way the rotation turns.
     """
-    L = vals.shape[-1]
-    pos = jnp.arange(L)
+    L, ax = x.shape[-1], x.ndim - 1
+    pos = _lane_pos(x)
+    take_a = _roll(pos, j, ax) == (pos ^ j)
+    return jnp.where(take_a, _roll(x, j, ax), _roll(x, L - j, ax))
+
+
+def _stage(vals: Array, idx: Array, j: int, dir_bit: int, descending: bool):
+    """One compare-exchange stage at distance ``j``.
+
+    A block sorts ascending where ``lane & dir_bit == 0`` (everywhere when
+    ``dir_bit == 0``), the other way where ``descending``.  Ties go to the
+    lower lane, so value/index pairs stay consistent between the two halves.
+    """
+    pos = _lane_pos(vals)
+    lower = (pos & j) == 0
+    up = ((pos & dir_bit) == 0) != descending
     pvals = _partner(vals, j)
     pidx = _partner(idx, j)
-    is_lower = (pos & j) == 0  # first element of its pair
-    ppos = pos ^ j
-    # lexicographic (value, position) strict less-than: self < partner
-    self_lt = (vals < pvals) | ((vals == pvals) & (pos < ppos))
-    take_min = jnp.logical_not(jnp.logical_xor(up, is_lower))  # up == is_lower
-    take_self = jnp.where(take_min, self_lt, jnp.logical_not(self_lt))
-    new_vals = jnp.where(take_self, vals, pvals)
-    new_idx = jnp.where(take_self, idx, pidx)
-    return new_vals, new_idx
+    self_lt = (vals < pvals) | ((vals == pvals) & lower)
+    take_self = (up == lower) == self_lt
+    return jnp.where(take_self, vals, pvals), jnp.where(take_self, idx, pidx)
+
+
+def _merge_groups(vals: Array, idx: Array, K: int, dir_bit: int,
+                  descending: bool):
+    """Sort every *bitonic* aligned K-lane group: the last log2 K stages."""
+    j = K // 2
+    while j >= 1:
+        vals, idx = _stage(vals, idx, j, dir_bit, descending)
+        j //= 2
+    return vals, idx
+
+
+def _sort_groups(vals: Array, idx: Array, K: int, dir_bit: int,
+                 descending: bool):
+    """Bitonic sort of every aligned K-lane group; direction as in ``_stage``."""
+    size = 2
+    while size <= K:
+        vals, idx = _merge_groups(vals, idx, size,
+                                  size if size < K else dir_bit, descending)
+        size *= 2
+    return vals, idx
 
 
 def bitonic_sort_kv(vals: Array, idx: Array, ascending: bool = True):
     """Full bitonic sort of (vals, idx) along the last axis (length = 2^p).
 
-    Static O(log^2 L) network of reshape/flip/min-max ops — maps to TPU VPU
-    shuffles; no gathers or data-dependent control flow.
+    Static O(log^2 L) network of lane rotations and selects: no gathers and
+    no data-dependent control flow.
     """
     L = vals.shape[-1]
     assert L & (L - 1) == 0, f"bitonic sort needs pow2 length, got {L}"
-    if L == 1:
-        return vals, idx
-    pos = jnp.arange(L)
-    size = 2
-    while size <= L:
-        up = (pos & size) == 0
-        if size == L:
-            up = jnp.ones((L,), bool) if ascending else jnp.zeros((L,), bool)
-        elif not ascending:
-            up = jnp.logical_not(up)
-        j = size // 2
-        while j >= 1:
-            vals, idx = _stage(vals, idx, j, up)
-            j //= 2
-        size *= 2
-    return vals, idx
+    return _sort_groups(vals, idx, L, 0, not ascending)
 
 
-def bitonic_merge_ascending(vals: Array, idx: Array):
-    """Sort a *bitonic* sequence ascending: the final log-L merge network only."""
-    L = vals.shape[-1]
-    up = jnp.ones((L,), bool)
-    j = L // 2
-    while j >= 1:
-        vals, idx = _stage(vals, idx, j, up)
-        j //= 2
-    return vals, idx
+def _reverse(x: Array) -> Array:
+    """Lane reversal, as the exchanges XOR 1, 2, ..., L/2 composed:
+    ``i XOR (L - 1) == L - 1 - i``."""
+    j = 1
+    while j < x.shape[-1]:
+        x = _partner(x, j)
+        j *= 2
+    return x
+
+
+def merge_topk_bitonic(av: Array, ai: Array, bv: Array, bi: Array, K: int):
+    """K smallest of ``a ∪ b`` in every aligned K-lane group, ascending.
+
+    ``a``'s groups ascend and ``b``'s descend, so the lanewise min holds
+    exactly the K smallest of the union and is bitonic; one log-K merge
+    network sorts it.
+    """
+    a_wins = av <= bv
+    return _merge_groups(jnp.where(a_wins, av, bv), jnp.where(a_wins, ai, bi),
+                         K, 0, False)
 
 
 def merge_topk_sorted(av: Array, ai: Array, bv: Array, bi: Array):
@@ -137,12 +173,53 @@ def merge_topk_sorted(av: Array, ai: Array, bv: Array, bi: Array):
     K smallest of the union and is bitonic; one merge network sorts it.
     O(log K) stages vs O(K log K) for a full re-sort.
     """
-    rbv = jnp.flip(bv, axis=-1)
-    rbi = jnp.flip(bi, axis=-1)
-    a_wins = av <= rbv
-    lo_v = jnp.where(a_wins, av, rbv)
-    lo_i = jnp.where(a_wins, ai, rbi)
-    return bitonic_merge_ascending(lo_v, lo_i)
+    return merge_topk_bitonic(av, ai, _reverse(bv), _reverse(bi),
+                              av.shape[-1])
+
+
+def kernel_k(k: int) -> int:
+    """Network width K inside a kernel: next_pow2(k), but at least 2 — a
+    network with no compare-exchange stage (K = 1) aborts the TPU compiler
+    (libtpu 0.0.34).  Kernels still return next_pow2(k) columns."""
+    return max(next_pow2(k), 2)
+
+
+def reduce_width(L: int, K: int) -> int:
+    """Lane width of ``reduce_topk``'s result for an L-wide tile."""
+    return max(K, min(L, LANES))
+
+
+def reduce_topk(vals: Array, idx: Array, K: int, *, descending: bool = False):
+    """K smallest of each row of an L-wide tile, L = K * 2^t.
+
+    Returns ``reduce_width(L, K)``-wide arrays whose lanes [0, K) hold the
+    winners sorted (ascending, or descending where ``descending``); the
+    lanes after them are don't-care.  The K-lane groups sort in alternating
+    directions and pair off at distances D = L/2, ..., K: the lower group
+    keeps the lanewise min of an ascending and a descending group, which is
+    the K smallest of both and bitonic, so one merge re-sorts it.  Pairs at
+    D >= LANES are aligned halves, and the upper half is dropped; below that
+    the partner arrives by lane rotations and the upper lanes go stale.
+    """
+    L = vals.shape[-1]
+    assert L % K == 0 and (L // K) & (L // K - 1) == 0, (L, K)
+
+    def direction(D):  # groups pairing at D sort in opposite directions
+        return (D, False) if D >= K else (0, descending)
+
+    D = L // 2
+    vals, idx = _sort_groups(vals, idx, K, *direction(D))
+    while D >= K:
+        if D >= LANES:
+            hi_v, hi_i = vals[..., D:], idx[..., D:]
+            vals, idx = vals[..., :D], idx[..., :D]
+        else:
+            hi_v, hi_i = _partner(vals, D), _partner(idx, D)
+        keep = vals <= hi_v
+        vals, idx = jnp.where(keep, vals, hi_v), jnp.where(keep, idx, hi_i)
+        D //= 2
+        vals, idx = _merge_groups(vals, idx, K, *direction(D))
+    return vals, idx
 
 
 # ---------------------------------------------------------------------------

@@ -5,6 +5,10 @@ from __future__ import annotations
 
 import jax
 
+# Scoped VMEM a kernel may claim: v5e has 128 MiB per core, and the
+# compiler's default scope is 16 MiB.
+VMEM_LIMIT = 64 << 20
+
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"

@@ -35,7 +35,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import topk as T
 from repro.kernels._backend import resolve_interpret
 from repro.core.distances import get_distance, matmul_finalize
-from repro.kernels.stream_topk import _tile_reduce_topk
+from repro.kernels.stream_topk import emit_running, fold_tile, init_running
 
 
 def _kernel(K, nj, nk, bm, bn, alpha, finalize, n_real, exclude_self,
@@ -53,10 +53,8 @@ def _kernel(K, nj, nk, bm, bn, alpha, finalize, n_real, exclude_self,
         out_v_ref, out_i_ref, acc, run_v, run_i = refs[-5:]
         i, j, kd = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-        @pl.when(jnp.logical_and(j == 0, kd == 0))
-        def _init_run():
-            run_v[...] = jnp.full_like(run_v, T.POS_INF)
-            run_i[...] = jnp.full_like(run_i, -1)
+        pl.when(jnp.logical_and(j == 0, kd == 0))(
+            lambda: init_running(run_v, run_i))
 
         @pl.when(kd == 0)
         def _init_acc():
@@ -87,27 +85,9 @@ def _kernel(K, nj, nk, bm, bn, alpha, finalize, n_real, exclude_self,
                 # per-ROW masks.  fp32 {0, 1} rather than i1: the mask block
                 # then shares the fp32 tiling of every other operand.
                 tile = jnp.where(qm_ref[...] != 0, tile, T.POS_INF)
-
-            def merge():
-                tv, ti = _tile_reduce_topk(tile, K, j * bn)
-                mv, mi = T.merge_topk_sorted(run_v[...], run_i[...], tv, ti)
-                run_v[...] = mv
-                run_i[...] = mi
-
-            if threshold_skip:
-                kth = run_v[:, K - 1 : K]
-
-                @pl.when(jnp.any(tile < kth))
-                def _maybe():
-                    merge()
-
-            else:
-                merge()
-
-            @pl.when(j == nj - 1)
-            def _emit():
-                out_v_ref[...] = run_v[...]
-                out_i_ref[...] = run_i[...]
+            fold_tile(run_v, run_i, tile, K, j * bn, threshold_skip)
+            pl.when(j == nj - 1)(
+                lambda: emit_running(out_v_ref, out_i_ref, run_v, run_i))
 
     return kernel
 
@@ -165,7 +145,8 @@ def fused_knn_pallas(
     assert gy.dtype in (jnp.float32, jnp.bfloat16, jnp.int8), gy.dtype
     m, d = fx.shape
     n = gy.shape[0]
-    K = T.next_pow2(k)
+    K = T.kernel_k(k)
+    Wr = T.reduce_width(bn, K)
     assert m % bm == 0 and n % bn == 0 and d % bd == 0
     assert bn % K == 0 and (bn // K) & (bn // K - 1) == 0, (bn, K)
     nj, nk = n // bn, d // bd
@@ -189,7 +170,7 @@ def fused_knn_pallas(
         pl.BlockSpec((1, bn), lambda i, j, kd: (0, j)),
     ]
     operands += [hx, hy]
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         _kernel(
             K,
             nj,
@@ -207,17 +188,17 @@ def fused_knn_pallas(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bm, K), lambda i, j, kd: (i, 0)),
-            pl.BlockSpec((bm, K), lambda i, j, kd: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, j, kd: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, j, kd: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, K), jnp.float32),
-            jax.ShapeDtypeStruct((m, K), jnp.int32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.float32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),
-            pltpu.VMEM((bm, K), jnp.float32),
-            pltpu.VMEM((bm, K), jnp.int32),
+            pltpu.VMEM((bm, Wr), jnp.float32),
+            pltpu.VMEM((bm, Wr), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
@@ -225,3 +206,4 @@ def fused_knn_pallas(
         interpret=interpret,
         name="fused_knn",
     )(*operands)
+    return vals[:, :T.next_pow2(k)], idx[:, :T.next_pow2(k)]

@@ -40,8 +40,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import topk as T
 from repro.core.distances import get_distance, matmul_finalize
-from repro.kernels._backend import resolve_interpret
-from repro.kernels.stream_topk import _tile_reduce_topk
+from repro.kernels._backend import VMEM_LIMIT, resolve_interpret
+from repro.kernels.stream_topk import emit_running, fold_tile, init_running
 
 
 def _kernel(K, W, nk, cell_cap, alpha, finalize, threshold_skip, scaled):
@@ -63,10 +63,8 @@ def _kernel(K, W, nk, cell_cap, alpha, finalize, threshold_skip, scaled):
         # pinned toolchain (the select is data-flow, so it cannot).
         dup = jnp.logical_and(j > 0, cell == probe_ref[i, jnp.maximum(j - 1, 0)])
 
-        @pl.when(jnp.logical_and(j == 0, kd == 0))
-        def _init_run():
-            run_v[...] = jnp.full_like(run_v, T.POS_INF)
-            run_i[...] = jnp.full_like(run_i, -1)
+        pl.when(jnp.logical_and(j == 0, kd == 0))(
+            lambda: init_running(run_v, run_i))
 
         @pl.when(kd == 0)
         def _init_acc():
@@ -89,28 +87,11 @@ def _kernel(K, W, nk, cell_cap, alpha, finalize, threshold_skip, scaled):
             # Pad slots arrive with hy == +inf; duplicate probe slots are
             # neutralized here (merging +inf is a no-op for the K-buffer).
             tile = jnp.where(dup, T.POS_INF, tile)
+            # Global PACKED slot ids: the probed cell's block offset.
+            fold_tile(run_v, run_i, tile, K, cell * cell_cap, threshold_skip)
 
-            def merge():
-                # Global PACKED slot ids: the probed cell's block offset.
-                tv, ti = _tile_reduce_topk(tile, K, cell * cell_cap)
-                mv, mi = T.merge_topk_sorted(run_v[...], run_i[...], tv, ti)
-                run_v[...] = mv
-                run_i[...] = mi
-
-            if threshold_skip:
-                kth = run_v[:, K - 1 : K]
-
-                @pl.when(jnp.any(tile < kth))
-                def _maybe():
-                    merge()
-
-            else:
-                merge()
-
-        @pl.when(jnp.logical_and(j == W - 1, kd == nk - 1))
-        def _emit():
-            out_v_ref[...] = run_v[...]
-            out_i_ref[...] = run_i[...]
+        pl.when(jnp.logical_and(j == W - 1, kd == nk - 1))(
+            lambda: emit_running(out_v_ref, out_i_ref, run_v, run_i))
 
     return kernel
 
@@ -162,7 +143,8 @@ def ivf_scan_pallas(
     m, d = fx.shape
     S = gy.shape[0]
     nt, W = probes.shape
-    K = T.next_pow2(k)
+    K = T.kernel_k(k)
+    Wr = T.reduce_width(cell_cap, K)
     assert m % bm == 0 and nt == m // bm, (m, bm, nt)
     assert S % cell_cap == 0 and d % bd == 0, (S, cell_cap, d, bd)
     assert cell_cap % K == 0 and (cell_cap // K) & (cell_cap // K - 1) == 0, (
@@ -189,16 +171,16 @@ def ivf_scan_pallas(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bm, K), lambda i, j, kd, pr: (i, 0)),
-            pl.BlockSpec((bm, K), lambda i, j, kd, pr: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, j, kd, pr: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, j, kd, pr: (i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, cell_cap), jnp.float32),
-            pltpu.VMEM((bm, K), jnp.float32),
-            pltpu.VMEM((bm, K), jnp.int32),
+            pltpu.VMEM((bm, Wr), jnp.float32),
+            pltpu.VMEM((bm, Wr), jnp.int32),
         ],
     )
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         _kernel(
             K,
             W,
@@ -211,12 +193,15 @@ def ivf_scan_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((m, K), jnp.float32),
-            jax.ShapeDtypeStruct((m, K), jnp.int32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.float32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            # Cell-wide blocks: past the 16 MiB default at cell_cap >= 1024.
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
         name="ivf_scan",
     )(probes, *operands)
+    return vals[:, :T.next_pow2(k)], idx[:, :T.next_pow2(k)]

@@ -29,6 +29,9 @@ from repro.kernels import stream_topk as _st
 from repro.kernels._backend import resolve_interpret
 
 
+_RESCORE_BLOCK_BYTES = 4 << 20
+
+
 def _pad_axis(x, mult, axis, value=0.0):
     pad = (-x.shape[axis]) % mult
     if not pad:
@@ -231,6 +234,25 @@ def fused_knn(
     return KNNResult(vals[:m, :k], idx[:m, :k])
 
 
+def _scan_tile_cap(K: int) -> int:
+    """Query rows per scan tile: the selection network unrolls into the
+    kernel, and at a fetch wider than a lane tile (K > 128) a 256-row tile
+    compiles in minutes where a 64-row tile compiles in seconds."""
+    return 256 if K <= T.LANES else 64
+
+
+def _drop_unprobed_tiles(vals, idx, cells, ncells, tile_m):
+    """Empty out query tiles none of whose probes is a cell in [0, ncells).
+
+    A shard of the sharded path can own none of a tile's probed cells; its
+    probe list then still names one cell, which the scan must not report.
+    """
+    hit = jnp.logical_and(cells >= 0, cells < ncells)
+    hit = jnp.any(hit.reshape(-1, tile_m * cells.shape[1]), axis=1)
+    ok = jnp.repeat(hit, tile_m)[:, None]
+    return jnp.where(ok, vals, T.POS_INF), jnp.where(ok, idx, -1)
+
+
 def ivf_scan_impl(
     q,
     db,
@@ -296,7 +318,7 @@ def ivf_scan_impl(
         gs = None
     if packed_live is not None:
         hy = jnp.where(packed_live[None, :], hy, T.POS_INF)
-    tile_m = min(tile_m, T.next_pow2(max(m, 8)))
+    tile_m = min(tile_m, T.next_pow2(max(m, 8)), _scan_tile_cap(K))
     fx = _pad_axis(_pad_axis(fx, tile_m, 0), bd, 1)
     gy = _pad_axis(gy, bd, 1)
     hx = _pad_axis(hx, tile_m, 0)
@@ -321,6 +343,7 @@ def ivf_scan_impl(
         threshold_skip=threshold_skip,
         interpret=interpret,
     )
+    vals, idx = _drop_unprobed_tiles(vals, idx, cells, ncells, tile_m)
     return KNNResult(vals[:m, :k], idx[:m, :k])
 
 
@@ -388,7 +411,7 @@ def pq_scan_impl(
         hy = jnp.where(packed_live[None, :], hy, T.POS_INF)
     qc = (None if centroids is None
           else pq_cell_bias(q, centroids, distance=distance))
-    tile_m = min(tile_m, T.next_pow2(max(m, 8)))
+    tile_m = min(tile_m, T.next_pow2(max(m, 8)), _scan_tile_cap(K))
     lut_flat = _pad_axis(lut_flat, tile_m, 0)
     hx = _pad_axis(hx, tile_m, 0)
     if qc is not None:
@@ -414,6 +437,7 @@ def pq_scan_impl(
         threshold_skip=threshold_skip,
         interpret=interpret,
     )
+    vals, idx = _drop_unprobed_tiles(vals, idx, cells, ncells, tile_m)
     return KNNResult(vals[:m, :k], idx[:m, :k])
 
 
@@ -468,8 +492,11 @@ def rescore_topk(
     hx = mf.hx(q).astype(jnp.float32)[:, None]
 
     # Pad: rows of queries, the d axis, and the candidate axis (to K * 2^t).
-    bm = min(bm, T.next_pow2(max(m, 8)))
     Kp_pad = K * T.next_pow2(-(-max(Kp, K) // K))
+    # A [bm, Kp, bd] fp32 candidate block stays within 4 MiB of VMEM (it is
+    # double-buffered): wide over-fetches take fewer query rows per block.
+    bm = min(bm, T.next_pow2(max(m, 8)),
+             max(8, _RESCORE_BLOCK_BYTES // (Kp_pad * bd * 4)))
     fx = _pad_axis(_pad_axis(fx, bm, 0), bd, 1)
     hx = _pad_axis(hx, bm, 0)
     cand = _pad_axis(_pad_axis(_pad_axis(cand, bm, 0), Kp_pad, 1), bd, 2)

@@ -20,9 +20,10 @@ trades MXU FLOPs (which the bandwidth-bound scan has to burn) for HBM bytes
 
 VMEM budget (DESIGN.md §PQ): the LUT block is bm·m·2^nbits·4 B — 4 MiB at
 the defaults (bm=256, m=16, nbits=8) — plus a transient one-hot
-[m·2^nbits, cell_cap] fp32 (2 MiB at cell_cap=128) and the [bm, K]
-K-buffers; comfortably inside the ~16 MiB VMEM, and the LUT block is
-revisited (not re-DMA'd) across the probe axis since its index map ignores j.
+[m·2^nbits, min(cell_cap, ADC_CHUNK)] fp32 (at most 4 MiB: a wider cell is
+scored in ADC_CHUNK-column pieces) and the K-buffers; inside the ~16 MiB
+VMEM, and the LUT block is revisited (not re-DMA'd) across the probe axis
+since its index map ignores j.
 
 Probe-list machinery is inherited verbatim from ``ivf_scan.py``: the
 per-query-tile union list rides in as a scalar-prefetch operand
@@ -31,8 +32,10 @@ read it, so a cell absent from the list is never DMA'd — unprobed cells cost
 zero HBM traffic.  Padding repeats the previous slot's cell; its candidates
 are neutralized arithmetically (tile → +inf — same pinned-toolchain
 rationale as ivf_scan).  ``qc`` is the residual-PQ cross term
-``alpha · fx · centroid[cell]`` (``core.pq.pq_cell_bias``), a [bm, 1]
-per-block operand.  Candidate indices are emitted in PACKED slot space.
+``alpha · fx · centroid[cell]`` (``core.pq.pq_cell_bias``).  A [bm, 1] block
+of it is not a legal TPU block, so the kernel fetches the lane-aligned
+[bm, 128] block holding the probed cell's column and selects that lane.
+Candidate indices are emitted in PACKED slot space.
 """
 from __future__ import annotations
 
@@ -45,8 +48,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import topk as T
 from repro.core.distances import get_distance, matmul_finalize
-from repro.kernels._backend import resolve_interpret
-from repro.kernels.stream_topk import _tile_reduce_topk
+from repro.kernels._backend import VMEM_LIMIT, resolve_interpret
+from repro.kernels.stream_topk import emit_running, fold_tile, init_running
+
+
+ADC_CHUNK = 256  # code columns per one-hot contraction in the kernel
 
 
 def adc_tile(lut_flat, codes_t, ncodes):
@@ -86,39 +92,27 @@ def _kernel(K, W, m, ncodes, cell_cap, finalize, threshold_skip, residual):
         # never control flow keyed on the scalar operand).
         dup = jnp.logical_and(j > 0, cell == probe_ref[i, jnp.maximum(j - 1, 0)])
 
-        @pl.when(j == 0)
-        def _init_run():
-            run_v[...] = jnp.full_like(run_v, T.POS_INF)
-            run_i[...] = jnp.full_like(run_i, -1)
+        pl.when(j == 0)(lambda: init_running(run_v, run_i))
 
-        t = adc_tile(lut_ref[...], codes_ref[...], ncodes)
+        # The one-hot operand is m·2^nbits x chunk fp32 (4 MiB at m = 16,
+        # nbits = 8): wider cells score chunk by chunk to stay in VMEM.
+        chunk = min(cell_cap, ADC_CHUNK)
+        t = jnp.concatenate(
+            [adc_tile(lut_ref[...], codes_ref[:, c:c + chunk], ncodes)
+             for c in range(0, cell_cap, chunk)], axis=1)
         if residual:
-            t = t + qc_ref[...]  # alpha·fx·centroid[cell], rank-1 per block
+            # alpha·fx·centroid[cell]: the cell's lane of its 128-lane block;
+            # a sum of one value and zeros, so the column is exact.
+            lane = jax.lax.broadcasted_iota(jnp.int32, qc_ref.shape, 1)
+            t = t + jnp.sum(jnp.where(lane == cell % T.LANES, qc_ref[...], 0.0),
+                            axis=1, keepdims=True)
         tile = finalize(t + hx_ref[...] + hy_ref[...])
         # Pad slots arrive with hy == +inf; duplicate probe slots die here.
         tile = jnp.where(dup, T.POS_INF, tile)
-
-        def merge():
-            # Global PACKED slot ids: the probed cell's block offset.
-            tv, ti = _tile_reduce_topk(tile, K, cell * cell_cap)
-            mv, mi = T.merge_topk_sorted(run_v[...], run_i[...], tv, ti)
-            run_v[...] = mv
-            run_i[...] = mi
-
-        if threshold_skip:
-            kth = run_v[:, K - 1 : K]
-
-            @pl.when(jnp.any(tile < kth))
-            def _maybe():
-                merge()
-
-        else:
-            merge()
-
-        @pl.when(j == W - 1)
-        def _emit():
-            out_v_ref[...] = run_v[...]
-            out_i_ref[...] = run_i[...]
+        # Global PACKED slot ids: the probed cell's block offset.
+        fold_tile(run_v, run_i, tile, K, cell * cell_cap, threshold_skip)
+        pl.when(j == W - 1)(
+            lambda: emit_running(out_v_ref, out_i_ref, run_v, run_i))
 
     return kernel
 
@@ -173,7 +167,8 @@ def pq_scan_pallas(
     mj, S = codes_t.shape
     assert luts.shape[1] == mj * ncodes, (luts.shape, mj, ncodes)
     nt, W = probes.shape
-    K = T.next_pow2(k)
+    K = T.kernel_k(k)
+    Wr = T.reduce_width(cell_cap, K)
     assert m % bm == 0 and nt == m // bm, (m, bm, nt)
     assert S % cell_cap == 0, (S, cell_cap)
     assert cell_cap % K == 0 and (cell_cap // K) & (cell_cap // K - 1) == 0, (
@@ -186,8 +181,9 @@ def pq_scan_pallas(
     ]
     operands = [luts, codes_t]
     if residual:
-        in_specs.append(pl.BlockSpec((bm, 1), lambda i, j, pr: (i, pr[i, j])))
-        operands.append(qc)
+        in_specs.append(pl.BlockSpec(
+            (bm, T.LANES), lambda i, j, pr: (i, pr[i, j] // T.LANES)))
+        operands.append(jnp.pad(qc, ((0, 0), (0, (-qc.shape[1]) % T.LANES))))
     in_specs += [
         pl.BlockSpec((bm, 1), lambda i, j, pr: (i, 0)),
         pl.BlockSpec((1, cell_cap), lambda i, j, pr: (0, pr[i, j])),
@@ -198,15 +194,15 @@ def pq_scan_pallas(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bm, K), lambda i, j, pr: (i, 0)),
-            pl.BlockSpec((bm, K), lambda i, j, pr: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, j, pr: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, j, pr: (i, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bm, K), jnp.float32),
-            pltpu.VMEM((bm, K), jnp.int32),
+            pltpu.VMEM((bm, Wr), jnp.float32),
+            pltpu.VMEM((bm, Wr), jnp.int32),
         ],
     )
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         _kernel(
             K,
             W,
@@ -219,12 +215,15 @@ def pq_scan_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((m, K), jnp.float32),
-            jax.ShapeDtypeStruct((m, K), jnp.int32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.float32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            # Cell-wide blocks: past the 16 MiB default at cell_cap >= 1024.
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
         name="pq_scan",
     )(probes, *operands)
+    return vals[:, :T.next_pow2(k)], idx[:, :T.next_pow2(k)]

@@ -30,7 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import topk as T
 from repro.kernels._backend import resolve_interpret
 from repro.core.distances import get_distance, matmul_finalize
-from repro.kernels.stream_topk import _tile_reduce_topk
 
 
 def _kernel(K, nk, alpha, finalize):
@@ -51,7 +50,8 @@ def _kernel(K, nk, alpha, finalize):
         @pl.when(kd == nk - 1)
         def _select():
             tile = finalize(alpha * acc[...] + hx_ref[...] + hyc_ref[...])
-            tv, tp = _tile_reduce_topk(tile, K, 0)
+            pos = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+            tv, tp = T.reduce_topk(tile, pos, K)
             out_v_ref[...] = tv
             out_p_ref[...] = tp
 
@@ -89,13 +89,14 @@ def rescore_topk_pallas(
     assert dist.matmul_form is not None, f"{distance} has no MXU form"
     m, d = fx.shape
     Kp = cand.shape[1]
-    K = T.next_pow2(k)
+    K = T.kernel_k(k)
+    Wr = T.reduce_width(Kp, K)
     assert cand.shape == (m, Kp, d), (cand.shape, fx.shape)
     assert m % bm == 0 and d % bd == 0, (fx.shape, bm, bd)
     assert Kp % K == 0 and (Kp // K) & (Kp // K - 1) == 0, (Kp, K)
     nk = d // bd
     grid = (m // bm, nk)
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         _kernel(K, nk, dist.matmul_form.alpha, matmul_finalize(dist)),
         grid=grid,
         in_specs=[
@@ -105,12 +106,12 @@ def rescore_topk_pallas(
             pl.BlockSpec((bm, Kp), lambda i, kd: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, K), lambda i, kd: (i, 0)),
-            pl.BlockSpec((bm, K), lambda i, kd: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, kd: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, kd: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, K), jnp.float32),
-            jax.ShapeDtypeStruct((m, K), jnp.int32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.float32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, Kp), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -119,3 +120,4 @@ def rescore_topk_pallas(
         interpret=interpret,
         name="rescore_topk",
     )(fx, cand, hx, hy_cand)
+    return vals[:, :T.next_pow2(k)], idx[:, :T.next_pow2(k)]

@@ -9,9 +9,10 @@ buffers, and push under a block lock.  The TPU mapping (DESIGN.md):
   heap-top filter      -> whole-tile `pl.when(any(tile < kth_best))` skip
   buffered heap push   -> bitonic tile-reduce + O(log K) bitonic top-k merge
 
-The selection network is static dataflow (reshape/flip/min/max), so it
-vectorizes across the 8x128 VPU lanes with no synchronization at all — the
-paper's lock disappears instead of being emulated.
+The selection network is static dataflow (lane rotations, selects and
+min/max), so it vectorizes across the 8x128 VPU lanes with no
+synchronization at all — the paper's lock disappears instead of being
+emulated.
 """
 from __future__ import annotations
 
@@ -26,58 +27,47 @@ from repro.core import topk as T
 from repro.kernels._backend import resolve_interpret
 
 
-def _tile_reduce_topk(tile, K, col_offset):
-    """Ascending per-row top-K of a (bm, bn) tile, bn = K * 2^t.
+def fold_tile(run_v, run_i, tile, K, col_offset, threshold_skip):
+    """Fold a (bm, bn) distance tile into the running top-K VMEM buffers.
 
-    Bitonic sort each K-wide group, then tree-merge groups pairwise keeping
-    the K smallest — all static shapes.
+    The buffers are ``Wr`` lanes wide, the K kept values
+    ascending in lanes [0, K); the tile's K smallest come out of the network
+    descending, so one bitonic merge keeps the K smallest of both.  With
+    ``threshold_skip`` a tile none of whose values beats a row's current
+    k-th best is skipped whole.
     """
-    bm, bn = tile.shape
-    g = bn // K
-    idx = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1) + col_offset
-    v = tile.reshape(bm, g, K)
-    i = idx.reshape(bm, g, K)
-    v, i = T.bitonic_sort_kv(v, i)
-    while g > 1:
-        v = v.reshape(bm, g // 2, 2, K)
-        i = i.reshape(bm, g // 2, 2, K)
-        v, i = T.merge_topk_sorted(v[:, :, 0], i[:, :, 0], v[:, :, 1], i[:, :, 1])
-        g //= 2
-    return v.reshape(bm, K), i.reshape(bm, K)
+    def merge():
+        idx = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1) + col_offset
+        tv, ti = T.reduce_topk(tile, idx, K, descending=True)
+        mv, mi = T.merge_topk_bitonic(run_v[...], run_i[...], tv, ti, K)
+        run_v[...] = mv
+        run_i[...] = mi
+
+    if threshold_skip:
+        pl.when(jnp.any(tile < run_v[:, K - 1 : K]))(merge)
+    else:
+        merge()
+
+
+def init_running(run_v, run_i):
+    run_v[...] = jnp.full_like(run_v, T.POS_INF)
+    run_i[...] = jnp.full_like(run_i, -1)
+
+
+def emit_running(out_v_ref, out_i_ref, run_v, run_i):
+    # The whole buffer: storing an int32 slice narrower than a lane tile
+    # aborts the TPU compiler (libtpu 0.0.34), so callers slice [:, :K].
+    out_v_ref[...] = run_v[...]
+    out_i_ref[...] = run_i[...]
 
 
 def _kernel(K, n_col_tiles, bn, threshold_skip):
     def kernel(x_ref, out_v_ref, out_i_ref, run_v, run_i):
         j = pl.program_id(1)
-
-        @pl.when(j == 0)
-        def _init():
-            run_v[...] = jnp.full_like(run_v, T.POS_INF)
-            run_i[...] = jnp.full_like(run_i, -1)
-
-        tile = x_ref[...]
-        col_offset = j * bn
-
-        def merge():
-            tv, ti = _tile_reduce_topk(tile, K, col_offset)
-            mv, mi = T.merge_topk_sorted(run_v[...], run_i[...], tv, ti)
-            run_v[...] = mv
-            run_i[...] = mi
-
-        if threshold_skip:
-            kth = run_v[:, K - 1 : K]  # current worst kept value per row
-
-            @pl.when(jnp.any(tile < kth))
-            def _maybe_merge():
-                merge()
-
-        else:
-            merge()
-
-        @pl.when(j == n_col_tiles - 1)
-        def _emit():
-            out_v_ref[...] = run_v[...]
-            out_i_ref[...] = run_i[...]
+        pl.when(j == 0)(lambda: init_running(run_v, run_i))
+        fold_tile(run_v, run_i, x_ref[...], K, j * bn, threshold_skip)
+        pl.when(j == n_col_tiles - 1)(
+            lambda: emit_running(out_v_ref, out_i_ref, run_v, run_i))
 
     return kernel
 
@@ -105,26 +95,27 @@ def stream_topk_pallas(
     interpret = resolve_interpret(interpret)
     threshold_skip = T.resolve_threshold_skip(threshold_skip, pallas=True)
     m, n = x.shape
-    K = T.next_pow2(k)
+    K = T.kernel_k(k)
+    Wr = T.reduce_width(bn, K)
     assert m % bm == 0 and n % bn == 0, (x.shape, bm, bn)
     assert bn % K == 0 and (bn // K) & (bn // K - 1) == 0, (bn, K)
     n_col_tiles = n // bn
     grid = (m // bm, n_col_tiles)
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         _kernel(K, n_col_tiles, bn, threshold_skip),
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=[
-            pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, Wr), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, K), jnp.float32),
-            jax.ShapeDtypeStruct((m, K), jnp.int32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.float32),
+            jax.ShapeDtypeStruct((m, Wr), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bm, K), jnp.float32),
-            pltpu.VMEM((bm, K), jnp.int32),
+            pltpu.VMEM((bm, Wr), jnp.float32),
+            pltpu.VMEM((bm, Wr), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -132,3 +123,4 @@ def stream_topk_pallas(
         interpret=interpret,
         name="stream_topk",
     )(x)
+    return vals[:, :T.next_pow2(k)], idx[:, :T.next_pow2(k)]

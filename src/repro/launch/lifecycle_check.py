@@ -40,10 +40,15 @@ CONFIGS = {
     "ivfpq": {"ivf_cells": 16, "nprobe": 8, "pq_m": 8},
 }
 
+_WRITE_SNIPPET = """
+import sys
+from repro.launch.lifecycle_check import CONFIGS, journal_and_crash
+journal_and_crash(sys.argv[1], CONFIGS[sys.argv[1]], sys.argv[2])
+"""
+
 _RECOVER_SNIPPET = """
 import sys
 import numpy as np
-import repro  # noqa: F401 (jax API compat shims)
 import repro.core.kmeans as KM
 
 def _tripwire(*a, **kw):
@@ -120,13 +125,17 @@ def main() -> None:
 
     repo_src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = repo_src + os.pathsep + env.get("PYTHONPATH", "")
     failures = []
     for name in args.configs:
         kw = CONFIGS[name]
         print(f"[lifecycle-check] {name}: journal + crash mid-append ({kw})")
-        snap = journal_and_crash(name, kw, args.out)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = repo_src + os.pathsep + env.get("PYTHONPATH", "")
+        # The writer is a child process too: this parent never touches JAX,
+        # so on a TPU host each child in turn is the one process on the chip.
+        subprocess.run([sys.executable, "-c", _WRITE_SNIPPET, name, args.out],
+                       check=True, env=env, timeout=600)
+        snap = os.path.join(args.out, name)
         proc = subprocess.run(
             [sys.executable, "-c", _RECOVER_SNIPPET, snap,
              os.path.join(args.out, f"{name}.expected.npz")],

@@ -85,7 +85,7 @@ from __future__ import annotations
 import argparse
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--corpus", type=int, default=16384)
     ap.add_argument("--queries", type=int, default=64, help="queries per batch")
@@ -161,7 +161,7 @@ def main():
                          "(DESIGN.md §17): auto = selectivity-driven, "
                          "pre = mask in scan, post = widened fetch + filter")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.restore and not args.snapshot_dir:
         ap.error("--restore needs --snapshot-dir")
     if args.wal and not args.snapshot_dir:
@@ -202,7 +202,10 @@ def main():
     import jax
     import numpy as np
 
+    from repro.compile_cache import configure
     from repro.configs import registry as REG
+
+    configure()
     from repro.configs.two_tower import serving_defaults
     from repro.models.nn import split_params
     from repro.serving import ServiceConfig, TwoTowerRetrievalService

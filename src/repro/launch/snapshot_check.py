@@ -35,10 +35,15 @@ CONFIGS = {
     "ivfpq": {"ivf_cells": 16, "nprobe": 8, "pq_m": 8},
 }
 
+_BUILD_SNIPPET = """
+import sys
+from repro.launch.snapshot_check import CONFIGS, build_and_snapshot
+build_and_snapshot(sys.argv[1], CONFIGS[sys.argv[1]], sys.argv[2])
+"""
+
 _RESTORE_SNIPPET = """
 import sys
 import numpy as np
-import repro  # noqa: F401 (jax API compat shims)
 import repro.core.kmeans as KM
 
 def _tripwire(*a, **kw):
@@ -100,13 +105,17 @@ def main() -> None:
 
     repo_src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = repo_src + os.pathsep + env.get("PYTHONPATH", "")
     failures = []
     for name in args.configs:
         kw = CONFIGS[name]
         print(f"[snapshot-check] {name}: build + churn + save ({kw})")
-        snap = build_and_snapshot(name, kw, args.out)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = repo_src + os.pathsep + env.get("PYTHONPATH", "")
+        # The builder is a child process too: this parent never touches JAX,
+        # so on a TPU host each child in turn is the one process on the chip.
+        subprocess.run([sys.executable, "-c", _BUILD_SNIPPET, name, args.out],
+                       check=True, env=env, timeout=600)
+        snap = os.path.join(args.out, name)
         proc = subprocess.run(
             [sys.executable, "-c", _RESTORE_SNIPPET, snap,
              os.path.join(args.out, f"{name}.expected.npz")],

@@ -867,10 +867,15 @@ class RetrievalIndex:
         # Padded main + mask are cached per main-segment version: re-padding
         # the whole corpus per query batch would be an O(n d) copy on the hot
         # path (the main segment only changes at build/compact/tombstone).
+        # They are placed row-sharded over db_axis, as the scorer reads them,
+        # so a search moves no corpus bytes between devices.
         if self._dev_version.get("main_padded") != self._version["main"]:
+            rows = jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec(self.db_axis))
             self._dev["main_padded"] = (
-                jnp.asarray(np.pad(self._main_vecs, ((0, n_pad - n), (0, 0)))),
-                jnp.asarray(np.pad(self._main_live, (0, n_pad - n))),
+                jax.device_put(
+                    np.pad(self._main_vecs, ((0, n_pad - n), (0, 0))), rows),
+                jax.device_put(np.pad(self._main_live, (0, n_pad - n)), rows),
             )
             self._dev_version["main_padded"] = self._version["main"]
         db, live_p = self._dev["main_padded"]  # pad rows are dead

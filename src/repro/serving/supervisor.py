@@ -306,6 +306,17 @@ class WorkerSupervisor:
     def __init__(self, cfg: SupervisorConfig = SupervisorConfig(), *,
                  impl: str | None = None, wire_dtype: str | None = None,
                  deadline_s: float | None = None, clock=time.monotonic):
+        from repro.kernels._backend import on_tpu
+
+        if on_tpu():
+            # This process has opened the TPU, and a chip belongs to one
+            # process at a time: a worker would wait out spawn_timeout_s.
+            raise RuntimeError(
+                "process workers need one chip per process, and this process "
+                "already holds the TPU, so no worker process can open it; "
+                "serve with in-process workers (--workers inproc). Giving "
+                "each worker a chip of its own is open work (ROADMAP Reach, "
+                "item 2).")
         self.cfg = cfg
         self.impl = impl
         self.wire_dtype = wire_dtype

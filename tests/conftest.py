@@ -14,10 +14,8 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Run from a plain checkout without installing: src/ on the path, then apply
-# the toolchain gates (repro._compat) before any test imports jax APIs.
+# Run from a plain checkout without installing: src/ on the path.
 sys.path.insert(0, os.path.join(REPO, "src"))
-import repro  # noqa: E402,F401  (side-effect: jax API compat shims)
 
 import _hypothesis_fallback  # noqa: E402
 
@@ -29,8 +27,6 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    # Toolchain gates first: snippets use jax.shard_map / AxisType directly.
-    code = "import repro  # noqa: F401 (jax API compat shims)\n" + textwrap.dedent(code)
     proc = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env,

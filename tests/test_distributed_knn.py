@@ -43,14 +43,22 @@ def test_ring_odd_vs_even_participants():
         x = jnp.asarray(np.random.randn(n, d).astype(np.float32))
         Dm = np.array(kref.pairwise_distance_ref(x, x)); np.fill_diagonal(Dm, np.inf)
         rv = np.sort(Dm, 1)[:, :k]
+        from repro.core import knn_allpairs
+        one = knn_allpairs(x, k, impl="fused")
         # P=8 (even) exercises the final half-step; P=4, P=2 sanity
         for P in (2, 4, 8):
             devs = jax.devices()[:P]
             mesh = jax.sharding.Mesh(np.array(devs), ("ring",))
-            fn = D.make_ring_allpairs(mesh, k=k)
-            res = fn(x, n)
-            err = float(np.max(np.abs(np.asarray(res.distances) - rv)))
-            assert err < 2e-3, (P, err)
+            for impl in ("jnp", "fused"):
+                fn = D.make_ring_allpairs(mesh, k=k, impl=impl)
+                res = fn(x, n)
+                err = float(np.max(np.abs(np.asarray(res.distances) - rv)))
+                assert err < 2e-3, (P, impl, err)
+            # The fused ring scores each pair as the one-device kernel does.
+            np.testing.assert_array_equal(np.asarray(res.distances),
+                                          np.asarray(one.distances))
+            np.testing.assert_array_equal(np.asarray(res.indices),
+                                          np.asarray(one.indices))
         print("OK")
     """)
 

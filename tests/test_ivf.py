@@ -340,3 +340,26 @@ def test_index_ivf_mesh_8dev():
         assert hits / float(10 * k) >= 0.9
         print("OK")
     """)
+
+
+def test_scan_of_a_shard_owning_no_probed_cell_reports_nothing():
+    """A query tile whose probes all fall outside [0, ncells) (a shard that
+    owns none of them) returns only empty slots from both scan kernels,
+    while a tile that owns one returns real candidates."""
+    from repro.core import build_ivfpq
+    from repro.kernels import ops
+
+    x = clustered_vectors(512, 16, n_clusters=8, seed=0)
+    ivf = build_ivf(x, 8, iters=4, seed=0)
+    cb, codes = build_ivfpq(x, ivf, 4, nbits=4, iters=4, seed=0)
+    q = jnp.asarray(x[:16])
+    cells = np.full((16, 2), 8 + 3, np.int32)  # another shard's cells
+    cells[8:, 0] = 1  # the second 8-query tile probes a cell of its own
+    cells = jnp.asarray(cells)
+    for res in (ops.ivf_scan(q, ivf.packed, cells, 4, cell_cap=ivf.cell_cap,
+                             tile_m=8),
+                ops.pq_scan(q, cb, codes, cells, 4, cell_cap=ivf.cell_cap,
+                            centroids=ivf.centroids, tile_m=8)):
+        v, i = np.asarray(res.distances), np.asarray(res.indices)
+        assert np.isinf(v[:8]).all() and (i[:8] == -1).all()
+        assert np.isfinite(v[8:]).all() and (i[8:] >= 0).all()

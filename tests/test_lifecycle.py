@@ -284,7 +284,6 @@ def test_any_crash_prefix_restores_acked_prefix(wal_history, i, extra):
 _KILL9_CHILD = """
 import sys
 import numpy as np
-import repro  # noqa: F401 (jax API compat shims)
 from repro.serving import LifecycleConfig, LifecycleIndex, RetrievalIndex
 
 snap = sys.argv[1]

@@ -184,7 +184,7 @@ def test_two_stage_recall_above_floor(seed, k, scan_dtype, impl, distance):
 
 def _iter_eqns(jaxpr):
     """All equations of a jaxpr, recursing into call/scan/cond sub-jaxprs."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subs(v):
         if isinstance(v, ClosedJaxpr):

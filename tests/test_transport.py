@@ -547,3 +547,13 @@ def test_restore_failure_ships_as_typed_error(fleet, tmp_path):
 def test_load_fleet_rejects_unknown_backend(fleet):
     with pytest.raises(ValueError, match="workers"):
         load_fleet(fleet.root, workers="threads")
+
+
+def test_proc_workers_refuse_a_process_holding_the_tpu(fleet, monkeypatch):
+    """A chip belongs to one process at a time: with a TPU backend the
+    supervisor refuses at once instead of waiting out the spawn timeout."""
+    from repro.kernels import _backend
+
+    monkeypatch.setattr(_backend, "on_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="one chip per process"):
+        load_fleet(fleet.root, workers="proc", replicas=1)
