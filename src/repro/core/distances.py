@@ -24,9 +24,16 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 Array = jnp.ndarray
+
+# Precision of every dot product an exact distance rests on.  On the TPU the
+# default for float32 operands is one bfloat16 pass (~1e-3 relative), which
+# ranks near neighbours wrongly; HIGHEST keeps float32.  The CPU computes in
+# float32 either way.
+EXACT = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,7 +94,7 @@ class MatmulForm:
     def pairwise(self, x: Array, y: Array, finalize) -> Array:
         fx = self.fx(x).astype(jnp.float32)
         gy = self.gy(y).astype(jnp.float32)
-        tile = self.alpha * fx @ gy.T
+        tile = self.alpha * jnp.matmul(fx, gy.T, precision=EXACT)
         tile = tile + self.hx(x)[:, None] + self.hy(y)[None, :]
         return finalize(tile)
 
@@ -101,7 +108,7 @@ def _sqeuclidean_acc(xc, yc, acc):
 
 
 def _dot_acc(xc, yc, acc):
-    return acc + jnp.einsum("mc,nc->mn", xc, yc)
+    return acc + jnp.einsum("mc,nc->mn", xc, yc, precision=EXACT)
 
 
 def _hellinger_acc(xc, yc, acc):
@@ -151,7 +158,8 @@ EUCLIDEAN = Distance(
 NEG_DOT = Distance(
     name="neg_dot",
     init=0.0,
-    accumulate=lambda xc, yc, acc: acc - jnp.einsum("mc,nc->mn", xc, yc),
+    accumulate=lambda xc, yc, acc: acc - jnp.einsum("mc,nc->mn", xc, yc,
+                                                    precision=EXACT),
     finalize=lambda a: a,
     matmul_form=MatmulForm(
         fx=lambda x: x,
@@ -167,7 +175,8 @@ NEG_COSINE = Distance(
     init=0.0,
     # Cumulative over chunks after the `pre` row-normalization (the only
     # non-chunkable step; the paper's dbar model allows such a prolog).
-    accumulate=lambda xc, yc, acc: acc - jnp.einsum("mc,nc->mn", xc, yc),
+    accumulate=lambda xc, yc, acc: acc - jnp.einsum("mc,nc->mn", xc, yc,
+                                                    precision=EXACT),
     finalize=lambda a: a,
     pre=lambda x: x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), _EPS),
     matmul_form=MatmulForm(

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core import topk as T
 from repro.core.distances import (
+    EXACT,
     Distance,
     QuantizedRows,
     get_distance,
@@ -343,7 +344,7 @@ def rescore(
     hy = mf.hy(rows).astype(jnp.float32).reshape(m, Kp)
     fx = mf.fx(queries).astype(jnp.float32)
     hx = mf.hx(queries).astype(jnp.float32)[:, None]
-    dots = jnp.einsum("md,mcd->mc", fx, gy)
+    dots = jnp.einsum("md,mcd->mc", fx, gy, precision=EXACT)
     tile = matmul_finalize(dist)(mf.alpha * dots + hx + hy)
     tile = jnp.where(cand_idx >= 0, tile, T.POS_INF)
     kk = min(k, Kp)
@@ -483,7 +484,8 @@ def quantized_scan(
                     t = t + jnp.take(cbt, cell_ids, axis=1)
             else:
                 dt = jax.lax.dynamic_slice(data, (col_off, 0), (tile_n, d))
-                dots = fxt @ dt.astype(jnp.float32).T  # per-tile upcast only
+                dots = jnp.matmul(fxt, dt.astype(jnp.float32).T,  # per-tile upcast
+                                  precision=EXACT)
                 t = mf.alpha * dots
                 if scale is not None:
                     t = t * jax.lax.dynamic_slice(scale, (0, col_off),

@@ -36,7 +36,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.distances import get_distance, gy_rows
+from repro.core.distances import EXACT, get_distance, gy_rows
 from repro.core.kmeans import lloyd
 
 Array = jnp.ndarray
@@ -135,15 +135,31 @@ def encode_pq(cb: PQCodebook, rows: Array) -> Array:
     return jnp.stack(cols, axis=1).astype(jnp.uint8)
 
 
+_DECODE_ROWS = 1 << 16
+
+
 @jax.jit
 def decode_pq(cb: PQCodebook, codes: Array) -> Array:
-    """Decoded rows [n, d] of codes [n, m] (gy/residual space)."""
+    """Decoded rows [n, d] of codes [n, m] (gy/residual space).
+
+    Decodes ``_DECODE_ROWS`` rows at a time: the TPU pads the gathered
+    [rows, m, 1, dsub] block's dsub-wide minor axis to 128 lanes (16x at
+    dsub = 8), which at millions of rows is more than the chip's HBM.
+    """
     n, m = codes.shape
     assert m == cb.m, (m, cb.m)
-    gathered = jnp.take_along_axis(
-        cb.codebooks[None], codes.astype(jnp.int32)[:, :, None, None],
-        axis=2)  # [n, m, 1, dsub]
-    return gathered.reshape(n, m * cb.dsub)
+
+    def rows(c):
+        gathered = jnp.take_along_axis(
+            cb.codebooks[None], c.astype(jnp.int32)[:, :, None, None],
+            axis=2)  # [rows, m, 1, dsub]
+        return gathered.reshape(c.shape[0], m * cb.dsub)
+
+    if n <= _DECODE_ROWS:
+        return rows(codes)
+    pad = (-n) % _DECODE_ROWS
+    chunks = jnp.pad(codes, ((0, pad), (0, 0))).reshape(-1, _DECODE_ROWS, m)
+    return jax.lax.map(rows, chunks).reshape(-1, m * cb.dsub)[:n]
 
 
 def build_pq(
@@ -278,7 +294,8 @@ def build_pq_luts(cb: PQCodebook, queries: Array, *,
     mq, d = fx.shape
     assert d == cb.m * cb.dsub, (d, cb.m, cb.dsub)
     fxr = fx.reshape(mq, cb.m, cb.dsub)
-    return mf.alpha * jnp.einsum("qjd,jcd->qjc", fxr, cb.codebooks)
+    return mf.alpha * jnp.einsum("qjd,jcd->qjc", fxr, cb.codebooks,
+                                 precision=EXACT)
 
 
 @functools.partial(jax.jit, static_argnames=("distance",))
@@ -295,4 +312,5 @@ def pq_cell_bias(queries: Array, centroids: Array, *,
     mf = get_distance(distance).matmul_form
     assert mf is not None, f"{distance} has no MXU form"
     fx = mf.fx(jnp.asarray(queries, jnp.float32)).astype(jnp.float32)
-    return mf.alpha * (fx @ jnp.asarray(centroids, jnp.float32).T)
+    return mf.alpha * jnp.matmul(fx, jnp.asarray(centroids, jnp.float32).T,
+                                 precision=EXACT)

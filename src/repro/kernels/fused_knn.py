@@ -34,7 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import topk as T
 from repro.kernels._backend import resolve_interpret
-from repro.core.distances import get_distance, matmul_finalize
+from repro.core.distances import EXACT, get_distance, matmul_finalize
 from repro.kernels.stream_topk import emit_running, fold_tile, init_running
 
 
@@ -65,6 +65,7 @@ def _kernel(K, nj, nk, bm, bn, alpha, finalize, n_real, exclude_self,
             fx_ref[...],
             gy_ref[...].astype(jnp.float32),
             (((1,), (1,)), ((), ())),
+            precision=EXACT,
             preferred_element_type=jnp.float32,
         )
 

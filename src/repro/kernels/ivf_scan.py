@@ -39,7 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import topk as T
-from repro.core.distances import get_distance, matmul_finalize
+from repro.core.distances import EXACT, get_distance, matmul_finalize
 from repro.kernels._backend import VMEM_LIMIT, resolve_interpret
 from repro.kernels.stream_topk import emit_running, fold_tile, init_running
 
@@ -75,6 +75,7 @@ def _kernel(K, W, nk, cell_cap, alpha, finalize, threshold_skip, scaled):
             fx_ref[...],
             gy_ref[...].astype(jnp.float32),
             (((1,), (1,)), ((), ())),
+            precision=EXACT,
             preferred_element_type=jnp.float32,
         )
 

@@ -429,6 +429,7 @@ def pq_scan_impl(
         hx,
         hy,
         k,
+        cells=cells,
         cell_cap=cell_cap,
         ncodes=pq_cb.ncodes,
         qc=qc,

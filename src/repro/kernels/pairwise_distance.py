@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.distances import EXACT
 from repro.kernels._backend import resolve_interpret
 
 
@@ -42,6 +43,7 @@ def _matmul_kernel(finalize, alpha, n_dchunks):
             fx_ref[...],
             gy_ref[...],
             (((1,), (1,)), ((), ())),
+            precision=EXACT,
             preferred_element_type=jnp.float32,
         )
 

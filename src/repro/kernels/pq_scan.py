@@ -47,7 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import topk as T
-from repro.core.distances import get_distance, matmul_finalize
+from repro.core.distances import EXACT, get_distance, matmul_finalize
 from repro.kernels._backend import VMEM_LIMIT, resolve_interpret
 from repro.kernels.stream_topk import emit_running, fold_tile, init_running
 
@@ -72,6 +72,7 @@ def adc_tile(lut_flat, codes_t, ncodes):
         lut_flat,
         oh.reshape(m * ncodes, cap),
         (((1,), (0,)), ((), ())),
+        precision=EXACT,
         preferred_element_type=jnp.float32,
     )
 
@@ -79,10 +80,10 @@ def adc_tile(lut_flat, codes_t, ncodes):
 def _kernel(K, W, m, ncodes, cell_cap, finalize, threshold_skip, residual):
     def kernel(probe_ref, lut_ref, codes_ref, *refs):
         if residual:
-            qc_ref, hx_ref, hy_ref = refs[:3]
+            qc_ref, own_ref, hx_ref, hy_ref = refs[:4]
         else:
             qc_ref = None
-            hx_ref, hy_ref = refs[:2]
+            own_ref, hx_ref, hy_ref = refs[:3]
         out_v_ref, out_i_ref, run_v, run_i = refs[-4:]
         i, j = pl.program_id(0), pl.program_id(1)
         cell = probe_ref[i, j]
@@ -107,8 +108,15 @@ def _kernel(K, W, m, ncodes, cell_cap, finalize, threshold_skip, residual):
             t = t + jnp.sum(jnp.where(lane == cell % T.LANES, qc_ref[...], 0.0),
                             axis=1, keepdims=True)
         tile = finalize(t + hx_ref[...] + hy_ref[...])
-        # Pad slots arrive with hy == +inf; duplicate probe slots die here.
-        tile = jnp.where(dup, T.POS_INF, tile)
+        # Pad slots arrive with hy == +inf; duplicate probe slots die here,
+        # and so does the cell for every query of the tile that did not
+        # probe it: the tile scans the union of its queries' cells, but an
+        # approximate shortlist drawn from the union holds more impostors
+        # than one drawn from the query's own nprobe cells.
+        own = jnp.max((own_ref[...] == cell).astype(jnp.int32), axis=1,
+                      keepdims=True) > 0
+        tile = jnp.where(jnp.logical_and(own, jnp.logical_not(dup)), tile,
+                         T.POS_INF)
         # Global PACKED slot ids: the probed cell's block offset.
         fold_tile(run_v, run_i, tile, K, cell * cell_cap, threshold_skip)
         pl.when(j == W - 1)(
@@ -137,6 +145,7 @@ def pq_scan_pallas(
     hy: jnp.ndarray,
     k: int,
     *,
+    cells: jnp.ndarray,
     cell_cap: int,
     ncodes: int,
     qc: jnp.ndarray | None = None,
@@ -153,7 +162,9 @@ def pq_scan_pallas(
     [mj, S] uint8 TRANSPOSED cell-packed codes (S = ncells · cell_cap on the
     lane axis — the streamed operand wants the long axis last); ``hx`` [m, 1]
     / ``hy`` [1, S] rank-1 terms, ``hy`` pre-set to +inf on dead slots;
-    ``qc`` [m, ncells] fp32 residual cross term (None = non-residual codes).
+    ``qc`` [m, ncells] fp32 residual cross term (None = non-residual codes);
+    ``cells`` [m, nprobe] int32 each query's own probed cells — a query
+    keeps candidates from those cells only.
 
     Returns (values [m, K], indices [m, K]) ascending, K = next_pow2(k),
     indices in PACKED slot space (−1 = empty).
@@ -184,6 +195,9 @@ def pq_scan_pallas(
         in_specs.append(pl.BlockSpec(
             (bm, T.LANES), lambda i, j, pr: (i, pr[i, j] // T.LANES)))
         operands.append(jnp.pad(qc, ((0, 0), (0, (-qc.shape[1]) % T.LANES))))
+    in_specs.append(pl.BlockSpec((bm, cells.shape[1]),
+                                 lambda i, j, pr: (i, 0)))
+    operands.append(cells)
     in_specs += [
         pl.BlockSpec((bm, 1), lambda i, j, pr: (i, 0)),
         pl.BlockSpec((1, cell_cap), lambda i, j, pr: (0, pr[i, j])),

@@ -79,13 +79,13 @@ def test_ivf_scan_compiles(one_chip):
 
 def test_pq_scan_compiles(one_chip):
     pq_m, ncodes = 16, 256
-    fn = lambda p, lut, codes, hx, hy, qc: pq_scan_pallas(  # noqa: E731
+    fn = lambda p, lut, codes, hx, hy, qc, cells: pq_scan_pallas(  # noqa: E731
         p, lut, codes, hx, hy, 10, cell_cap=CAP, ncodes=ncodes, qc=qc,
-        interpret=False)
+        cells=cells, interpret=False)
     _compile(fn, *_shapes(one_chip, ((1, CELLS), I32),
                           ((M, pq_m * ncodes), F32), ((pq_m, CELLS * CAP), U8),
                           ((M, 1), F32), ((1, CELLS * CAP), F32),
-                          ((M, CELLS), F32)))
+                          ((M, CELLS), F32), ((M, 16), I32)))
 
 
 def test_rescore_topk_compiles(one_chip):
