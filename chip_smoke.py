@@ -12,15 +12,17 @@ One chip:
   (b) exact flat serving of 2^20 x 128 rows through ``RetrievalIndex`` and
       ``QueryEngine`` (fused and jnp scorers), with upserts, deletes and a
       ``compact()``, checked against a brute force over the live rows;
-  (c) IVF-PQ serving of 2^18 x 128 rows (pq_m = 16, fused scan and
-      rescore) with its recall@10 against brute force held to the
-      repository's 0.9 floor;
+  (c) IVF-PQ serving of 2^20 x 128 rows (2048 cells, nprobe 64, pq_m =
+      16, overfetch 64; fused scan and rescore) with its recall@10 against
+      brute force held to the repository's 0.9 floor, and how each query's
+      true neighbours spread over cells against nprobe;
   (d) the serving CLI, ``repro.launch.serve --impl fused --pq-m 16``, in
       this process.
 
 Four chips (each against the same computation on one chip):
   ring all-pairs at phase (a)'s size; mesh-sharded exact flat serving (the
-  CLI's ``--mesh``); the sharded IVF-PQ scan.
+  CLI's ``--mesh``) at phase (b)'s; the sharded IVF-PQ scan at phase (c)'s
+  n, 4096 cells, nprobe 64, overfetch 32.
 
 Exits non-zero, with no result line, when JAX finds no TPU or any phase
 fails.  Wall times, compile times and peak bytes printed on the way are
@@ -40,13 +42,17 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 K_SERVE = 10
 RECALL_FLOOR = 0.9  # tests/test_pq.py RECALL_FLOOR
-# IVF-PQ serving (phase c and its four-chip twin) at 2^18 rows: ~256 rows
-# per cell, and the probe count and over-fetch that reach the recall floor
-# on this data (CPU run of the same path: recall 0.955).  At 2^20 rows the
-# query's cluster holds 16k rows, and the shortlist, capped at cell_cap =
-# 512 by the scan kernel, keeps too few of them: recall@10 0.526 on the
-# chip at 4096 cells and nprobe 16.
-IVF_N, IVF_CELLS, NPROBE, PQ_M, OVERFETCH = 1 << 18, 1024, 16, 16, 32
+# IVF-PQ serving at 2^20 rows.  The corpus is 64 tight Gaussian clusters,
+# so a query's cluster holds 16k rows in ~32 of phase (c)'s 2048 cells, and
+# its ten true neighbours lie in ~8 cells spread over all of them: the
+# probe list covers the cluster (nprobe 64), and the ADC shortlist is 1024
+# wide (overfetch 64) so that PQ error does not push them out.  The
+# four-chip twin checks equality with one chip, not the recall floor, at
+# 4096 cells and a 512-wide shortlist (recall@10 ~0.90), which builds and
+# compiles in half the time.  PERF.md records the measurements behind both.
+IVF_N, PQ_M = 1 << 20, 16
+IVF_CELLS, NPROBE, OVERFETCH = 2048, 64, 64
+SHARDED_CELLS, SHARDED_NPROBE, SHARDED_OVERFETCH = 4096, 64, 32
 
 
 def log(msg: str) -> None:
@@ -263,6 +269,24 @@ def phase_flat(n=1 << 20, d=128, nq=256, seed=1):
     return truth
 
 
+def cell_spread(q, ivf, true_rows):
+    """Where the true top-k of each query lie: distinct cells, and the share
+    of them inside the query's p nearest cells, against p."""
+    import numpy as np
+
+    from repro.core.ivf import probe_cells
+
+    cells = np.asarray(ivf.slot_of_row)[np.asarray(true_rows)] // ivf.cell_cap
+    held = np.array([len(set(r)) for r in cells.tolist()])
+    ps = [p for p in (16, 32, 64, 128, 256) if p <= ivf.ncells]
+    near = np.asarray(probe_cells(q, ivf.centroids, ps[-1], impl="fused"))
+    cover = [np.mean([np.isin(c, o[:p]).mean() for c, o in zip(cells, near)])
+             for p in ps]
+    log(f"  true top-{true_rows.shape[1]} per query lie in {held.mean():.2f} "
+        f"distinct cells (max {held.max()}); share in the query's p nearest "
+        "cells: " + ", ".join(f"p={p}: {c:.4f}" for p, c in zip(ps, cover)))
+
+
 def phase_ivfpq(n=IVF_N, d=128, nq=256, seed=2):
     import numpy as np
 
@@ -282,6 +306,7 @@ def phase_ivfpq(n=IVF_N, d=128, nq=256, seed=2):
         f"{ivf.packed.shape[0]} packed slots")
     res = timed("QueryEngine.search (warm)", eng.search, q)
     _, _, ref_i = brute_topk(q, x, K_SERVE)
+    cell_spread(q, ivf, ref_i)
     recall = overlap(res.ids, ref_i)
     log(f"  IVF-PQ recall@{K_SERVE} = {recall:.4f} "
         f"(nprobe={NPROBE}, pq_m={PQ_M}, overfetch={OVERFETCH}; "
@@ -359,13 +384,13 @@ def phase_sharded_ivfpq(n=IVF_N, d=128, nq=256, seed=2):
     x, q = corpus_and_queries(n, nq, d, seed)
     q = jnp.asarray(q)
     t0 = time.perf_counter()
-    ivf = build_ivf(x, IVF_CELLS, impl="fused")
+    ivf = build_ivf(x, SHARDED_CELLS, impl="fused")
     cb, codes = build_ivfpq(x, ivf, PQ_M, impl="fused")
     live = packed_live(ivf)
     log(f"  IVF-PQ build on one chip: {time.perf_counter() - t0:.3f}s "
         f"(cell_cap {ivf.cell_cap})")
-    kw = dict(k=K_SERVE, nprobe=NPROBE, cell_cap=ivf.cell_cap, impl="fused",
-              overfetch=OVERFETCH)
+    kw = dict(k=K_SERVE, nprobe=SHARDED_NPROBE, cell_cap=ivf.cell_cap,
+              impl="fused", overfetch=SHARDED_OVERFETCH)
 
     # One chip: every shard's pipeline in turn, then one merge.
     S_loc = ivf.packed.shape[0] // P
@@ -397,7 +422,8 @@ def phase_sharded_ivfpq(n=IVF_N, d=128, nq=256, seed=2):
     assert_same_result("sharded IVF-PQ vs one chip", res.distances,
                        res.indices, one_v, one_i, q, x)
     _, _, ref_i = brute_topk(q, x, K_SERVE)
-    log(f"  sharded IVF-PQ recall@{K_SERVE} = {overlap(res.indices, ref_i):.4f}")
+    log(f"  sharded IVF-PQ recall@{K_SERVE} = "
+        f"{overlap(res.indices, ref_i):.4f} (reported, not checked)")
 
 
 # ---------------------------------------------------------------------------

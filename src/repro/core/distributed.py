@@ -248,11 +248,12 @@ def ring_allpairs_shard(
         cols = vis_block.astype(x_local.dtype)
         if impl == "fused":
             tv, ti = fused_block(x_local, cols, src, False)
-            tv = jnp.where(active, tv, T.POS_INF)
+            tv, ti = jnp.where(active, tv, T.POS_INF), jnp.where(active, ti, -1)
             run_v, run_i = T.merge_topk_sorted(run_v, run_i, tv, ti)
             if sym:
                 tv, ti = fused_block(cols, x_local, p, False)
                 tv = jnp.where(active, tv, T.POS_INF)
+                ti = jnp.where(active, ti, -1)
         else:
             tile = _local_tile(x_local, cols, dist, impl)
             tile = jnp.where(active, masked(tile, p, src, False), T.POS_INF)

@@ -87,15 +87,9 @@ def _partner(x: Array, j: int) -> Array:
     """``x`` at lane ``i XOR j``: one rotation by j, one by L - j, a select.
 
     Which of the two rotations brings lane ``i XOR j`` is read off a rotated
-    lane iota, so the select holds whichever way the rotation turns.  At
-    j >= LANES the partners are whole lane tiles, swapped pairwise by
-    aligned slices (a kernel's rotation across lane tiles scrambled the
-    sort on the chip).
+    lane iota, so the select holds whichever way the rotation turns.
     """
     L, ax = x.shape[-1], x.ndim - 1
-    if j % LANES == 0:
-        blocks = [x[..., b * j:(b + 1) * j] for b in range(L // j)]
-        return jnp.concatenate([blocks[b ^ 1] for b in range(L // j)], axis=-1)
     pos = _lane_pos(x)
     take_a = _roll(pos, j, ax) == (pos ^ j)
     return jnp.where(take_a, _roll(x, j, ax), _roll(x, L - j, ax))

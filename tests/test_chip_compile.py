@@ -13,12 +13,15 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.pq import PQCodebook, PQCodes
+from repro.kernels import ops
 from repro.kernels.fused_knn import fused_knn_pallas
 from repro.kernels.ivf_scan import ivf_scan_pallas
 from repro.kernels.pq_scan import pq_scan_pallas
 from repro.kernels.rescore import rescore_topk_pallas
 
 M, N, D, CAP, CELLS = 256, 4096, 128, 256, 64
+WIDE_CAP, NPROBE = 1024, 16  # wide scans go through the ops wrappers
 
 
 @pytest.fixture(scope="module")
@@ -51,7 +54,7 @@ def _shapes(sharding, *specs):
     return [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in specs]
 
 
-F32, I8, U8, I32 = jnp.float32, jnp.int8, jnp.uint8, jnp.int32
+F32, I8, U8, I32, B = jnp.float32, jnp.int8, jnp.uint8, jnp.int32, jnp.bool_
 
 
 @pytest.mark.parametrize("k,d,gy", [(10, D, F32), (10, D, I8), (100, 256, F32)],
@@ -94,3 +97,36 @@ def test_rescore_topk_compiles(one_chip):
         fx, cand, hx, hy, 10, interpret=False)
     _compile(fn, *_shapes(one_chip, ((128, D), F32), ((128, kp, D), F32),
                           ((128, 1), F32), ((128, kp), F32)))
+
+
+# A fetch K wider than a lane tile: the wrappers' 64-row scan tiles, the
+# scoped-VMEM limit and the network's halving steps past 128 lanes
+# (K = 512 pairs off a 1024-lane cell block; K = 1024 sorts it whole).
+@pytest.mark.parametrize("k", [320, 1000], ids=["K512", "K1024"])
+def test_pq_scan_compiles_wide(one_chip, k):
+    S = CELLS * WIDE_CAP
+    fn = lambda q, cbk, codes, hy, cells, cent, live: ops.pq_scan(  # noqa: E731
+        q, PQCodebook(cbk), PQCodes(codes, hy), cells, k,
+        cell_cap=WIDE_CAP, centroids=cent, packed_live=live,
+        interpret=False).indices
+    _compile(fn, *_shapes(one_chip, ((M, D), F32), ((16, 256, D // 16), F32),
+                          ((S, 16), U8), ((S,), F32), ((M, NPROBE), I32),
+                          ((CELLS, D), F32), ((S,), B)))
+
+
+def test_ivf_scan_compiles_wide(one_chip):
+    S = CELLS * WIDE_CAP
+    fn = lambda q, db, cells, live: ops.ivf_scan(  # noqa: E731
+        q, db, cells, 320, cell_cap=WIDE_CAP, packed_live=live,
+        interpret=False).indices
+    _compile(fn, *_shapes(one_chip, ((M, D), F32), ((S, D), F32),
+                          ((M, NPROBE), I32), ((S,), B)))
+
+
+@pytest.mark.parametrize("kp", [512, 1024])
+def test_rescore_topk_compiles_wide(one_chip, kp):
+    # The wrapper shrinks the query block so [bm, Kp, d] fits in VMEM.
+    fn = lambda q, db, cand: ops.rescore_topk(  # noqa: E731
+        q, db, cand, 10, interpret=False).indices
+    _compile(fn, *_shapes(one_chip, ((M, D), F32), ((N, D), F32),
+                          ((M, kp), I32)))

@@ -13,7 +13,7 @@ SETTINGS = dict(max_examples=40, deadline=None)
 @hypothesis.settings(**SETTINGS)
 @hypothesis.given(
     rows=st.integers(1, 8),
-    logl=st.integers(0, 7),
+    logl=st.integers(0, 9),
     seed=st.integers(0, 100_000),
     ascending=st.booleans(),
 )
@@ -34,7 +34,7 @@ def test_bitonic_sort_matches_jnp_sort(rows, logl, seed, ascending):
 
 @hypothesis.settings(**SETTINGS)
 @hypothesis.given(
-    rows=st.integers(1, 6), logk=st.integers(0, 6), seed=st.integers(0, 100_000)
+    rows=st.integers(1, 6), logk=st.integers(0, 9), seed=st.integers(0, 100_000)
 )
 def test_merge_topk_sorted(rows, logk, seed):
     """min(a, reverse(b)) + bitonic merge == K smallest of the union."""
@@ -48,6 +48,55 @@ def test_merge_topk_sorted(rows, logk, seed):
                                  jnp.asarray(b), jnp.asarray(bi))
     ref = np.sort(np.concatenate([a, b], axis=1), axis=1)[:, :K]
     np.testing.assert_array_equal(np.asarray(mv), ref)
+
+
+@hypothesis.settings(**SETTINGS)
+@hypothesis.given(
+    rows=st.integers(1, 4), logk=st.integers(1, 8), t=st.integers(0, 3),
+    seed=st.integers(0, 100_000), descending=st.booleans(),
+)
+def test_reduce_topk_oracle(rows, logk, t, seed, descending):
+    """Lanes [0, K) of an L = K * 2^t tile's reduction: its K smallest."""
+    K = 2 ** logk
+    L = K * 2 ** t
+    hypothesis.assume(L <= 512)
+    g = np.random.default_rng(seed)
+    vals = g.standard_normal((rows, L), dtype=np.float32)
+    idx = np.broadcast_to(np.arange(L, dtype=np.int32), (rows, L))
+    rv, ri = T.reduce_topk(jnp.asarray(vals), jnp.asarray(idx), K,
+                           descending=descending)
+    assert rv.shape[-1] == T.reduce_width(L, K)
+    rv, ri = np.asarray(rv)[:, :K], np.asarray(ri)[:, :K]
+    ref = np.sort(vals, axis=1)[:, :K]
+    np.testing.assert_array_equal(rv, ref[:, ::-1] if descending else ref)
+    np.testing.assert_array_equal(np.take_along_axis(vals, ri, axis=1), rv)
+
+
+@hypothesis.settings(**SETTINGS)
+@hypothesis.given(
+    rows=st.integers(1, 4), logk=st.integers(1, 9), groups=st.sampled_from(
+        [1, 2, 4]), seed=st.integers(0, 100_000),
+)
+def test_merge_topk_bitonic_oracle(rows, logk, groups, seed):
+    """Ascending a, descending b, K-lane groups side by side: each group's
+    result is the K smallest of its two groups, ascending."""
+    K = 2 ** logk
+    hypothesis.assume(K * groups <= 512)
+    g = np.random.default_rng(seed)
+    a = np.sort(g.standard_normal((rows, groups, K), dtype=np.float32), -1)
+    b = np.sort(g.standard_normal((rows, groups, K), dtype=np.float32),
+                -1)[..., ::-1]
+    ai = np.broadcast_to(np.arange(groups * K, dtype=np.int32),
+                         (rows, groups * K))
+    bi = ai + groups * K
+    mv, mi = T.merge_topk_bitonic(
+        jnp.asarray(a.reshape(rows, -1)), jnp.asarray(ai),
+        jnp.asarray(b.reshape(rows, -1)), jnp.asarray(bi), K)
+    ref = np.sort(np.concatenate([a, b], -1), -1)[..., :K]
+    np.testing.assert_array_equal(np.asarray(mv), ref.reshape(rows, -1))
+    both = np.concatenate([a.reshape(rows, -1), b.reshape(rows, -1)], 1)
+    np.testing.assert_array_equal(
+        np.take_along_axis(both, np.asarray(mi), axis=1), np.asarray(mv))
 
 
 @hypothesis.settings(**SETTINGS)
