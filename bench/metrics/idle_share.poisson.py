@@ -1,0 +1,6 @@
+"""Share (%) of the traced window in which the device ran nothing."""
+from bench import readers
+
+
+def read(ctx):
+    return readers.idle_share(ctx)
