@@ -12,6 +12,9 @@ device results, aggregated into p50/p99/mean latency and queries/sec.  The
 first recorded batch after a (re)compile is tagged separately so steady-state
 numbers are not polluted by compilation (EXPERIMENTS.md §Serving).
 
+span: the serving path's profiler spans (``knn.<name>``), written into the
+trace of an open ``jax.profiler`` session and costing a flag check otherwise.
+
 scan_bytes_per_query: the analytic HBM-traffic model of the two-stage
 quantized scan (DESIGN.md §Quantized) and its IVF cell-probed extension
 (``ncells``/``nprobe`` — DESIGN.md §IVF) — what the precision and IVF
@@ -19,6 +22,10 @@ sweep benchmarks report next to measured qps so the bandwidth claims are
 auditable.
 """
 from __future__ import annotations
+
+import time
+
+import jax
 
 _UNROLL = [False]
 
@@ -243,6 +250,18 @@ def unrolled() -> bool:
     return _UNROLL[0]
 
 
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """Host span ``knn.<name>`` with ``args`` as its trace stats.
+
+    Recorded only while a profiler session is open (``jax.profiler.trace``,
+    ``bench/run.py --trace 1``); otherwise entering it checks a flag.  An
+    arg known only inside the span is added with ``.set_metadata(...)``.
+    Never opened inside a jitted function: device stages are named with
+    ``jax.named_scope`` instead.
+    """
+    return jax.profiler.TraceAnnotation("knn." + name, **args)
+
+
 class ServingMeter:
     """Accumulates (batch_size, wall_seconds) samples from the query engine."""
 
@@ -251,8 +270,9 @@ class ServingMeter:
 
     def reset(self) -> None:
         self._sizes: list[int] = []
-        self._secs: list[float] = []
-        self._compile_secs: list[float] = []
+        # (start, end) of each batch on the ``time.perf_counter`` clock.
+        self._spans: list[tuple[float, float]] = []
+        self._compile_spans: list[tuple[float, float]] = []
         # Per-worker dispatch accounting (the shard router's failover path):
         # worker key -> [calls, failures, total seconds, last error].
         self._shard: dict[str, list] = {}
@@ -261,12 +281,17 @@ class ServingMeter:
         self._wal: list = [0, 0, 0.0]
         self._handoffs: list[float] = []
 
-    def record(self, batch_size: int, seconds: float, *, compile_batch: bool = False) -> None:
+    def record(self, batch_size: int, seconds: float, *,
+               compile_batch: bool = False, end: float | None = None) -> None:
+        """One batch of ``seconds`` ending at ``end`` (``time.perf_counter``
+        clock; now when None)."""
+        end = time.perf_counter() if end is None else float(end)
+        span = (end - float(seconds), end)
         if compile_batch:
-            self._compile_secs.append(float(seconds))
+            self._compile_spans.append(span)
             return
         self._sizes.append(int(batch_size))
-        self._secs.append(float(seconds))
+        self._spans.append(span)
 
     def record_wal(self, records: int, nbytes: int, seconds: float) -> None:
         """One fsync-acked WAL append (serving.lifecycle durability path)."""
@@ -303,8 +328,12 @@ class ServingMeter:
                 "error_rate": failures / calls if calls else 0.0}
 
     @property
+    def _secs(self) -> list[float]:
+        return [e - s for s, e in self._spans]
+
+    @property
     def n_batches(self) -> int:
-        return len(self._secs)
+        return len(self._spans)
 
     @property
     def n_queries(self) -> int:
@@ -321,8 +350,16 @@ class ServingMeter:
         return xs[rank] * 1e3
 
     def qps(self) -> float:
-        total = sum(self._secs)
-        return self.n_queries / total if total > 0 else float("nan")
+        """Metered queries over the wall time from the first metered batch's
+        start to the last one's end: host time between batches counts,
+        compile batches inside that time do not."""
+        if not self._spans:
+            return float("nan")
+        t0 = min(s for s, _ in self._spans)
+        t1 = max(e for _, e in self._spans)
+        wall = (t1 - t0) - sum(e - s for s, e in self._compile_spans
+                               if s >= t0 and e <= t1)
+        return self.n_queries / wall if wall > 0 else float("nan")
 
     def summary(self) -> dict:
         out = {
@@ -333,8 +370,8 @@ class ServingMeter:
             "p99_ms": self.latency_ms(99),
             "mean_ms": (sum(self._secs) / len(self._secs) * 1e3
                         if self._secs else float("nan")),
-            "compile_batches": len(self._compile_secs),
-            "compile_s": sum(self._compile_secs),
+            "compile_batches": len(self._compile_spans),
+            "compile_s": sum(e - s for s, e in self._compile_spans),
         }
         if self._shard:
             sh = self.shard_summary()

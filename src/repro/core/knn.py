@@ -769,10 +769,14 @@ def ivfpq_query(
     k = min(k, n)
     ncells, cap = ivf.ncells, ivf.cell_cap
     nprobe = min(nprobe, ncells)
-    cells = IVF.probe_cells(queries, ivf.centroids, nprobe,
-                            distance=distance, impl=impl)
-    live_p = IVF.packed_live(ivf, db_live)
-    allowed_p = _packed_allowed(ivf, q_allowed)
+    # Stage scopes (metadata only) name the device ops in a profile; the
+    # fused scan names its own (kernels/ops.py pq_scan_impl).
+    with jax.named_scope("knn.probe"):
+        cells = IVF.probe_cells(queries, ivf.centroids, nprobe,
+                                distance=distance, impl=impl)
+    with jax.named_scope("knn.live_mask"):
+        live_p = IVF.packed_live(ivf, db_live)
+        allowed_p = _packed_allowed(ivf, q_allowed)
     k_scan = scan_width(n, k, overfetch)
     if impl == "fused":
         from repro.kernels import ops as kops
@@ -784,20 +788,28 @@ def ivfpq_query(
             centroids=ivf.centroids if residual else None, distance=distance,
             packed_live=live_p, threshold_skip=threshold_skip).indices
         if allowed_p is not None:
-            ok = jnp.take_along_axis(
-                allowed_p, jnp.clip(cand, 0, allowed_p.shape[1] - 1), axis=1)
-            cand = jnp.where(ok, cand, -1)
+            with jax.named_scope("knn.live_mask"):
+                ok = jnp.take_along_axis(
+                    allowed_p, jnp.clip(cand, 0, allowed_p.shape[1] - 1),
+                    axis=1)
+                cand = jnp.where(ok, cand, -1)
     else:
-        probed = jnp.any(
-            cells[:, :, None] == jnp.arange(ncells)[None, None, :], axis=1)
-        cbias = (pq_cell_bias(queries, ivf.centroids, distance=distance)
-                 if residual else None)
-        cand = quantized_scan(
-            queries, pq_codes, k_scan, distance=distance, db_live=live_p,
-            probed=probed, cell_cap=cap, pq_codebook=pq_cb, cell_bias=cbias,
-            q_allowed=allowed_p, threshold_skip=threshold_skip).indices
-    safe = jnp.clip(cand, 0, ivf.row_of_slot.shape[0] - 1)
-    rows = jnp.where(cand >= 0, jnp.take(ivf.row_of_slot, safe), -1)
-    rows = _mask_excluded_rows(rows, exclude_rows)
-    return rescore(queries, database, rows, k, distance=distance,
-                   impl="fused" if impl == "fused" else "jnp")
+        with jax.named_scope("knn.scan"):
+            probed = jnp.any(
+                cells[:, :, None] == jnp.arange(ncells)[None, None, :],
+                axis=1)
+        with jax.named_scope("knn.lut"):
+            cbias = (pq_cell_bias(queries, ivf.centroids, distance=distance)
+                     if residual else None)
+        with jax.named_scope("knn.scan"):
+            cand = quantized_scan(
+                queries, pq_codes, k_scan, distance=distance, db_live=live_p,
+                probed=probed, cell_cap=cap, pq_codebook=pq_cb,
+                cell_bias=cbias, q_allowed=allowed_p,
+                threshold_skip=threshold_skip).indices
+    with jax.named_scope("knn.rescore"):
+        safe = jnp.clip(cand, 0, ivf.row_of_slot.shape[0] - 1)
+        rows = jnp.where(cand >= 0, jnp.take(ivf.row_of_slot, safe), -1)
+        rows = _mask_excluded_rows(rows, exclude_rows)
+        return rescore(queries, database, rows, k, distance=distance,
+                       impl="fused" if impl == "fused" else "jnp")

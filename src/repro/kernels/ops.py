@@ -403,43 +403,51 @@ def pq_scan_impl(
     assert K <= cell_cap, (
         f"fetch width K={K} exceeds the cell block ({cell_cap}); lower k or "
         "rebuild with a larger cell_cap")
-    luts = build_pq_luts(pq_cb, q, distance=distance)
-    lut_flat = luts.reshape(m, pq_cb.m * pq_cb.ncodes)
-    hx = mf.hx(q).astype(jnp.float32)[:, None]
+    # Stage scopes (metadata only) name the device ops in a profile.
+    with jax.named_scope("knn.lut"):
+        luts = build_pq_luts(pq_cb, q, distance=distance)
+        lut_flat = luts.reshape(m, pq_cb.m * pq_cb.ncodes)
+        hx = mf.hx(q).astype(jnp.float32)[:, None]
     hy = pq_codes.hy.astype(jnp.float32)[None, :]
     if packed_live is not None:
-        hy = jnp.where(packed_live[None, :], hy, T.POS_INF)
-    qc = (None if centroids is None
-          else pq_cell_bias(q, centroids, distance=distance))
+        with jax.named_scope("knn.live_mask"):
+            hy = jnp.where(packed_live[None, :], hy, T.POS_INF)
+    with jax.named_scope("knn.lut"):
+        qc = (None if centroids is None
+              else pq_cell_bias(q, centroids, distance=distance))
     tile_m = min(tile_m, T.next_pow2(max(m, 8)), _scan_tile_cap(K))
-    lut_flat = _pad_axis(lut_flat, tile_m, 0)
-    hx = _pad_axis(hx, tile_m, 0)
-    if qc is not None:
-        qc = _pad_axis(qc, tile_m, 0)
-    # Pad queries replicate the last row's probes: real cells, wider unions.
-    pad = lut_flat.shape[0] - m
-    if pad:
-        cells = jnp.concatenate([cells, jnp.broadcast_to(
-            cells[-1:], (pad, cells.shape[1]))], axis=0)
-    probes = tile_probe_lists(cells, ncells, tile_m)
-    vals, idx = _pq.pq_scan_pallas(
-        probes,
-        lut_flat,
-        pq_codes.codes.T,
-        hx,
-        hy,
-        k,
-        cells=cells,
-        cell_cap=cell_cap,
-        ncodes=pq_cb.ncodes,
-        qc=qc,
-        distance=distance,
-        bm=tile_m,
-        threshold_skip=threshold_skip,
-        interpret=interpret,
-    )
-    vals, idx = _drop_unprobed_tiles(vals, idx, cells, ncells, tile_m)
-    return KNNResult(vals[:m, :k], idx[:m, :k])
+    with jax.named_scope("knn.lut"):
+        lut_flat = _pad_axis(lut_flat, tile_m, 0)
+        hx = _pad_axis(hx, tile_m, 0)
+        if qc is not None:
+            qc = _pad_axis(qc, tile_m, 0)
+    with jax.named_scope("knn.probe_union"):
+        # Pad queries replicate the last row's probes: real cells, wider
+        # unions.
+        pad = lut_flat.shape[0] - m
+        if pad:
+            cells = jnp.concatenate([cells, jnp.broadcast_to(
+                cells[-1:], (pad, cells.shape[1]))], axis=0)
+        probes = tile_probe_lists(cells, ncells, tile_m)
+    with jax.named_scope("knn.scan"):
+        vals, idx = _pq.pq_scan_pallas(
+            probes,
+            lut_flat,
+            pq_codes.codes.T,
+            hx,
+            hy,
+            k,
+            cells=cells,
+            cell_cap=cell_cap,
+            ncodes=pq_cb.ncodes,
+            qc=qc,
+            distance=distance,
+            bm=tile_m,
+            threshold_skip=threshold_skip,
+            interpret=interpret,
+        )
+        vals, idx = _drop_unprobed_tiles(vals, idx, cells, ncells, tile_m)
+        return KNNResult(vals[:m, :k], idx[:m, :k])
 
 
 pq_scan = functools.partial(

@@ -15,9 +15,18 @@ small closed set of shapes.  The engine sits between the two:
   results.  This is the classic serving pattern (cf. faiss-serving /
   TF-Serving batching) in its smallest honest form; async arrival is the
   caller's concern.
-* **metering** — every flushed batch is timed blocking-on-device and recorded
-  in an ``accounting.ServingMeter`` (first batch at a fresh shape is tagged
-  as a compile batch so steady-state p50/p99/qps stay clean).
+* **metering** — every chunk is timed blocking-on-device and recorded in an
+  ``accounting.ServingMeter`` (first batch at a fresh shape is tagged as a
+  compile batch so steady-state p50/p99/qps stay clean).  The same interval
+  is the ``knn.chunk`` span: with a profiler session open
+  (``jax.profiler.trace``) the engine writes host spans ``knn.flush``
+  (args ``flush_id``, ``rows``) ⊃ ``knn.chunk`` (``rows``, ``padded``, the
+  pow2 bucket, and ``cold``, 1 at a new shape key) ⊃ ``knn.pad`` (host pad
+  copy and shape-key bookkeeping), ``knn.state`` (the ``before_batch`` hook;
+  inside the index, uploads and training, arg ``rebuilt``),
+  ``knn.dispatch`` (query upload and program enqueue, in the index),
+  ``knn.wait`` (``block_until_ready``) and ``knn.fetch`` (results leaving
+  the search path).  One span a flush and one a chunk, none a query.
 """
 from __future__ import annotations
 
@@ -74,6 +83,7 @@ class QueryEngine:
         # recurrence as a compile batch would skew the steady-state stats.
         self._seen_shapes: set = set()
         self._live_main: int | None = None
+        self._flushes = 0  # the ``flush_id`` of the next flush's span
 
     def rebind(self, index) -> None:
         """Point the engine at a replacement index (rebuild, restore, or a
@@ -113,7 +123,8 @@ class QueryEngine:
         # read below then sees the post-swap index (DESIGN.md §16).
         hook = getattr(self.index, "before_batch", None)
         if hook is not None:
-            hook()
+            with accounting.span("state"):
+                hook()
         q = np.asarray(queries, np.float32)
         assert q.ndim == 2, q.shape
         if len(q) == 0:  # nothing to score, nothing to meter
@@ -139,8 +150,10 @@ class QueryEngine:
             from repro.serving.shards import merge_shard_status
 
             status = merge_shard_status(out_s)
-        return SearchResult(jnp.concatenate(out_v), jnp.concatenate(out_i),
-                            coverage=coverage, shard_status=status)
+        with accounting.span("fetch"):
+            return SearchResult(jnp.concatenate(out_v),
+                                jnp.concatenate(out_i), coverage=coverage,
+                                shard_status=status)
 
     def _search_padded(self, chunk: np.ndarray, k: int,
                        f=None) -> SearchResult:
@@ -148,36 +161,48 @@ class QueryEngine:
 
         m = len(chunk)
         mp = self._bucket(m)
-        qp = np.zeros((mp, chunk.shape[1]), np.float32)
-        qp[:m] = chunk
-        # A shape is "cold" (compile expected) once per (batch, k, index
-        # shape signature) — delta appends that stay inside the current
-        # capacity/fetch buckets do NOT recompile and stay steady-state.
-        sig = self.index.shape_signature(k)
-        if sig[0] != self._live_main:  # new packed main: old keys stranded
-            self._seen_shapes = {s for s in self._seen_shapes
-                                 if s[2][0] == sig[0]}
-            self._live_main = sig[0]
-        # The filter's compiled-shape contribution: which predicates exist,
-        # the execution mode, and the exclusion width (a traced-array dim).
-        fkey = None if f is None else (f.mode, f.tenant is not None,
-                                       f.allowed_ids is not None,
-                                       F.exclusion_width(f))
-        shape_key = (mp, k, sig, fkey)
-        cold = shape_key not in self._seen_shapes
-        self._seen_shapes.add(shape_key)
+        # The meter times the same interval as the span, so an operator's
+        # p50/p99 and the trace agree.
         t0 = time.perf_counter()
-        if f is None:
-            res = self.index.search(qp, k)
-        else:
-            res = self.index.search(qp, k, filter=F.pad_rows(f, mp))
-        # Block on the array legs only: coverage is host numpy and
-        # shard_status is plain python — neither has device futures.
-        jax.block_until_ready((res.distances, res.ids))
-        self.meter.record(m, time.perf_counter() - t0, compile_batch=cold)
-        cov = None if res.coverage is None else res.coverage[:m]
-        return SearchResult(res.distances[:m], res.ids[:m], coverage=cov,
-                            shard_status=res.shard_status)
+        with accounting.span("chunk", rows=m, padded=mp) as sp:
+            with accounting.span("pad"):
+                qp = np.zeros((mp, chunk.shape[1]), np.float32)
+                qp[:m] = chunk
+                # A shape is "cold" (compile expected) once per (batch, k,
+                # index shape signature) — delta appends that stay inside
+                # the current capacity/fetch buckets do NOT recompile and
+                # stay steady-state.
+                sig = self.index.shape_signature(k)
+                if sig[0] != self._live_main:  # new packed main: keys stranded
+                    self._seen_shapes = {s for s in self._seen_shapes
+                                         if s[2][0] == sig[0]}
+                    self._live_main = sig[0]
+                # The filter's compiled-shape contribution: which predicates
+                # exist, the execution mode, and the exclusion width (a
+                # traced-array dim).
+                fkey = None if f is None else (f.mode, f.tenant is not None,
+                                               f.allowed_ids is not None,
+                                               F.exclusion_width(f))
+                shape_key = (mp, k, sig, fkey)
+                cold = shape_key not in self._seen_shapes
+                self._seen_shapes.add(shape_key)
+            sp.set_metadata(cold=int(cold))
+            if f is None:
+                res = self.index.search(qp, k)
+            else:
+                res = self.index.search(qp, k, filter=F.pad_rows(f, mp))
+            # Block on the array legs only: coverage is host numpy and
+            # shard_status is plain python — neither has device futures.
+            with accounting.span("wait"):
+                jax.block_until_ready((res.distances, res.ids))
+            with accounting.span("fetch"):
+                cov = None if res.coverage is None else res.coverage[:m]
+                out = SearchResult(res.distances[:m], res.ids[:m],
+                                   coverage=cov,
+                                   shard_status=res.shard_status)
+        t1 = time.perf_counter()
+        self.meter.record(m, t1 - t0, compile_batch=cold, end=t1)
+        return out
 
     # -- micro-batch queue --------------------------------------------------
 
@@ -196,7 +221,10 @@ class QueryEngine:
             return {}
         reqs, vecs = zip(*self._queue.items())
         self._queue = {}
-        res = self.search(np.stack(vecs), k)
-        dv = np.asarray(res.distances)
-        di = np.asarray(res.ids)
-        return {r: (dv[i], di[i]) for i, r in enumerate(reqs)}
+        self._flushes += 1
+        with accounting.span("flush", flush_id=self._flushes, rows=len(reqs)):
+            res = self.search(np.stack(vecs), k)
+            with accounting.span("fetch"):
+                dv = np.asarray(res.distances)
+                di = np.asarray(res.ids)
+                return {r: (dv[i], di[i]) for i, r in enumerate(reqs)}

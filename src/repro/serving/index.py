@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import accounting
 from repro.core import topk as T
 from repro.core.distances import QUANTIZABLE, canonical_scan_dtype, quantize_rows
 from repro.core.knn import ivf_query, ivfpq_query, knn_query, two_stage_query
@@ -98,12 +99,13 @@ def _drop_disallowed(vals, idx, allowed):
 
 def _externalize(vals, idx, ids, k_out):
     """Row indices -> external ids, padded out to fetch width ``k_out``."""
-    safe = jnp.clip(idx, 0, ids.shape[0] - 1)
-    ok = idx >= 0  # -1 where masked/padded (val == +inf)
-    ext = jnp.where(ok, jnp.take(ids, safe, axis=0), jnp.int32(-1))
-    if vals.shape[-1] < k_out:  # scorers clamp k to the row count
-        vals, ext = T.pad_topk(vals, ext, k_out)
-    return vals, ext
+    with jax.named_scope("knn.merge"):
+        safe = jnp.clip(idx, 0, ids.shape[0] - 1)
+        ok = idx >= 0  # -1 where masked/padded (val == +inf)
+        ext = jnp.where(ok, jnp.take(ids, safe, axis=0), jnp.int32(-1))
+        if vals.shape[-1] < k_out:  # scorers clamp k to the row count
+            vals, ext = T.pad_topk(vals, ext, k_out)
+        return vals, ext
 
 
 @functools.partial(jax.jit, static_argnames=("k_out", "nprobe", "overfetch",
@@ -172,8 +174,9 @@ def _segment_candidates_quantized(q, vecs, qrows, live, ids, allowed=None, *,
 @functools.partial(jax.jit, static_argnames=("k",))
 def _merge_candidates(av, ai, bv, bi, *, k):
     """Merge two ascending equal-width candidate sets, keep k smallest."""
-    mv, mi = T.merge_topk_sorted(av, ai, bv, bi)
-    return T.finalize_topk(mv, mi, k)
+    with jax.named_scope("knn.merge"):
+        mv, mi = T.merge_topk_sorted(av, ai, bv, bi)
+        return T.finalize_topk(mv, mi, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -661,37 +664,42 @@ class RetrievalIndex:
         trivially-true filter takes this exact code path (bit-identical to
         unfiltered search, pinned by tests/test_filters.py).
         """
-        q = jnp.asarray(queries, jnp.float32)
-        assert q.ndim == 2 and q.shape[1] == self.dim, q.shape
-        k = int(k)
-        assert k >= 1
-        if filter is not None:
-            from repro.serving import filters as F
+        with accounting.span("state") as sp:
+            before = dict(self._dev_version)
+            dev = self._device_state()
+            sp.set_metadata(rebuilt=int(self._dev_version != before))
+        with accounting.span("dispatch"):
+            q = jnp.asarray(queries, jnp.float32)
+            assert q.ndim == 2 and q.shape[1] == self.dim, q.shape
+            k = int(k)
+            assert k >= 1
+            if filter is not None:
+                from repro.serving import filters as F
 
-            f = F.normalize(filter, q.shape[0])
-            if f is not None:
-                return self._search_filtered(q, k, f)
-        k_out = T.next_pow2(k)
-        dev = self._device_state()
+                f = F.normalize(filter, q.shape[0])
+                if f is not None:
+                    return self._search_filtered(q, k, f)
+            k_out = T.next_pow2(k)
 
-        sets = []
-        if len(self._main_vecs):
-            sets.append(self._main_candidates(q, k_out, dev))
-        if self._delta_n:
-            vecs, live, ids = dev["delta"]
-            sets.append(_segment_candidates(
-                q, vecs, live, ids, k_out=k_out,
-                distance=self.distance, impl=self.impl))
-        if not sets:
-            m = q.shape[0]
-            return SearchResult(jnp.full((m, k), T.POS_INF, jnp.float32),
-                                jnp.full((m, k), -1, jnp.int32))
-        if len(sets) == 1:
-            vals, ids = T.finalize_topk(*sets[0], k)
+            sets = []
+            if len(self._main_vecs):
+                sets.append(self._main_candidates(q, k_out, dev))
+            if self._delta_n:
+                vecs, live, ids = dev["delta"]
+                sets.append(_segment_candidates(
+                    q, vecs, live, ids, k_out=k_out,
+                    distance=self.distance, impl=self.impl))
+            if not sets:
+                m = q.shape[0]
+                return SearchResult(jnp.full((m, k), T.POS_INF, jnp.float32),
+                                    jnp.full((m, k), -1, jnp.int32))
+            if len(sets) == 1:
+                with jax.named_scope("knn.merge"):
+                    vals, ids = T.finalize_topk(*sets[0], k)
+                return SearchResult(vals, ids)
+            (av, ai), (bv, bi) = sets
+            vals, ids = _merge_candidates(av, ai, bv, bi, k=k)
             return SearchResult(vals, ids)
-        (av, ai), (bv, bi) = sets
-        vals, ids = _merge_candidates(av, ai, bv, bi, k=k)
-        return SearchResult(vals, ids)
 
     # -- filtered search (DESIGN.md §17) -------------------------------------
 
