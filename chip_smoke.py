@@ -15,7 +15,8 @@ One chip:
   (c) IVF-PQ serving of 2^20 x 128 rows (2048 cells, nprobe 64, pq_m =
       16, overfetch 64; fused scan and rescore) with its recall@10 against
       brute force held to the repository's 0.9 floor, and how each query's
-      true neighbours spread over cells against nprobe;
+      true neighbours spread over cells against nprobe; first, the ADC
+      tile's scores on the chip against a float64 gather-sum;
   (d) the serving CLI, ``repro.launch.serve --impl fused --pq-m 16``, in
       this process.
 
@@ -287,11 +288,64 @@ def cell_spread(q, ivf, true_rows):
         "cells: " + ", ".join(f"p={p}: {c:.4f}" for p, c in zip(ps, cover)))
 
 
+def check_adc_exact(bm=64, cap=1024, m=PQ_M, ncodes=256, ncells=8, seed=3,
+                    interpret=False):
+    """``adc_tile`` as Mosaic lowers it keeps float32 accuracy: scores of a
+    Pallas kernel around it, at the batch cell's tile (bm 64, cell_cap 1024,
+    m 16, 256 codes), within 4 float32 ulps of each row's magnitude of a
+    float64 gather-sum, as ``tests/test_pq.py`` holds the interpreter to.
+    A one-piece bf16 LUT, which the bound must refuse, is logged beside."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels._backend import VMEM_LIMIT
+    from repro.kernels.pq_scan import adc_tile, split_lut
+
+    rng = np.random.default_rng(seed)
+    L, S = m * ncodes, ncells * cap
+    lut = (rng.standard_normal((bm, m, ncodes))
+           * 10.0 ** rng.uniform(-3, 1.5, (bm, m, 1))).astype(np.float32)
+    codes = rng.integers(0, ncodes, (m, S)).astype(np.uint8)
+
+    def kernel(lut_ref, codes_ref, out_ref):
+        out_ref[...] = adc_tile(lut_ref, codes_ref, ncodes)
+
+    got = np.asarray(pl.pallas_call(
+        kernel, grid=(ncells,),
+        in_specs=[pl.BlockSpec((3, bm, L), lambda j: (0, 0, 0)),
+                  pl.BlockSpec((m, cap), lambda j: (0, j))],
+        out_specs=pl.BlockSpec((bm, cap), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((bm, S), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(split_lut(jnp.asarray(lut.reshape(bm, L))), jnp.asarray(codes)))
+
+    def gather_sum(table):
+        return table[:, np.arange(m)[:, None], codes].astype(np.float64).sum(
+            axis=1)
+
+    want = gather_sum(lut)
+    mag = gather_sum(np.abs(lut)).max(axis=1, keepdims=True)
+    ulp = np.spacing(mag.astype(np.float32)).astype(np.float64)
+    err = float((np.abs(got - want) / ulp).max())
+    one_piece = gather_sum(lut.astype(jnp.bfloat16).astype(np.float32))
+    err_bf16 = float((np.abs(one_piece - want) / ulp).max())
+    log(f"  adc_tile on {jax.devices()[0].device_kind}: max error "
+        f"{err:.3f} ulps of the row magnitude (bound 4; a one-piece bf16 "
+        f"LUT reads {err_bf16:.1f})")
+    assert err <= 4 and err_bf16 > 4, (err, err_bf16)
+    return err
+
+
 def phase_ivfpq(n=IVF_N, d=128, nq=256, seed=2):
     import numpy as np
 
     from repro.serving import EngineConfig, QueryEngine, RetrievalIndex
 
+    check_adc_exact()
     x, q = corpus_and_queries(n, nq, d, seed)
     t0 = time.perf_counter()
     index = RetrievalIndex.build(np.arange(n), x, impl="fused",

@@ -390,7 +390,8 @@ def quantized_scan(
     the jaxpr peak-shape test in tests/test_quantized.py).
 
     ADC path (DESIGN.md §PQ) — the reference for ``kernels/pq_scan.py``: the
-    per-query LUTs build once (``build_pq_luts``) and each column tile
+    per-query LUTs build and split into exact bf16 pieces once
+    (``build_pq_luts``, ``kernels.pq_scan.split_lut``) and each column tile
     scores through the SAME one-hot MXU contraction as the kernel
     (``kernels.pq_scan.adc_tile``), so at tile_n = cell_cap the two paths
     are bit-identical under the interpreter (tested).  ``cell_bias``
@@ -410,7 +411,7 @@ def quantized_scan(
     (DESIGN.md §17).
     """
     from repro.core.pq import PQCodes, build_pq_luts
-    from repro.kernels.pq_scan import adc_tile
+    from repro.kernels.pq_scan import adc_tile, split_lut
 
     threshold_skip = T.resolve_threshold_skip(threshold_skip, pallas=False)
     dist = get_distance(distance)
@@ -426,7 +427,8 @@ def quantized_scan(
         assert pq_codebook is not None, "PQCodes scan needs its codebook"
         ncodes = pq_codebook.ncodes
         luts = build_pq_luts(pq_codebook, queries, distance=distance)
-        fx = _pad_rows(luts.reshape(m_real, -1), tile_m)  # flattened LUTs
+        # Flattened LUTs, split into the kernel's bf16 pieces [3, rows, L].
+        fx = split_lut(_pad_rows(luts.reshape(m_real, -1), tile_m))
     else:
         fx = _pad_rows(mf.fx(queries).astype(jnp.float32), tile_m)
     hx = _pad_rows(mf.hx(queries).astype(jnp.float32)[:, None], tile_m)
@@ -459,12 +461,13 @@ def quantized_scan(
         assert pq and cell_cap is not None
         cell_bias = _pad_rows(cell_bias, tile_m)
 
-    n_row_tiles = fx.shape[0] // tile_m
+    n_row_tiles = hx.shape[0] // tile_m
     n_col_tiles = data.shape[1 if pq else 0] // tile_n
 
     def row_block(_, r):
         row_off = r * tile_m
-        fxt = jax.lax.dynamic_slice(fx, (row_off, 0), (tile_m, fx.shape[1]))
+        fxt = jax.lax.dynamic_slice_in_dim(fx, row_off, tile_m,
+                                           axis=fx.ndim - 2)
         hxt = jax.lax.dynamic_slice(hx, (row_off, 0), (tile_m, 1))
         pbt = (None if probed is None else jax.lax.dynamic_slice(
             probed, (row_off, 0), (tile_m, probed.shape[1])))

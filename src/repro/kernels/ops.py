@@ -418,6 +418,7 @@ def pq_scan_impl(
     tile_m = min(tile_m, T.next_pow2(max(m, 8)), _scan_tile_cap(K))
     with jax.named_scope("knn.lut"):
         lut_flat = _pad_axis(lut_flat, tile_m, 0)
+        lut_split = _pq.split_lut(lut_flat)
         hx = _pad_axis(hx, tile_m, 0)
         if qc is not None:
             qc = _pad_axis(qc, tile_m, 0)
@@ -432,7 +433,7 @@ def pq_scan_impl(
     with jax.named_scope("knn.scan"):
         vals, idx = _pq.pq_scan_pallas(
             probes,
-            lut_flat,
+            lut_split,
             pq_codes.codes.T,
             hx,
             hy,

@@ -18,12 +18,22 @@ contraction on the MXU: the code block [m, cell_cap] expands to a one-hot
 trades MXU FLOPs (which the bandwidth-bound scan has to burn) for HBM bytes
 (which it does not have): the database stream drops from d to m bytes/row.
 
-VMEM budget (DESIGN.md §PQ): the LUT block is bm·m·2^nbits·4 B — 4 MiB at
-the defaults (bm=256, m=16, nbits=8) — plus a transient one-hot
-[m·2^nbits, min(cell_cap, ADC_CHUNK)] fp32 (at most 4 MiB: a wider cell is
-scored in ADC_CHUNK-column pieces) and the K-buffers; inside the ~16 MiB
-VMEM, and the LUT block is revisited (not re-DMA'd) across the probe axis
-since its index map ignores j.
+The MXU multiplies bf16.  The one-hot's 0s and 1s are exact in bf16, and the
+float32 LUT splits exactly into three bf16 pieces (``split_lut``: hi, mid
+and lo hold 3 x 8 significand bits, so hi + mid + lo == lut bit for bit).
+The pieces stack into one [3, rows, m·2^nbits] bf16 operand, split once a
+batch outside the kernel; a query tile's [3, bm, ·] block merges to
+[3·bm, ·] and one default-precision pass with float32 accumulation scores
+all three: the products are exact, so the tile keeps float32 accuracy
+without a multi-pass float32 contraction.
+
+VMEM budget (DESIGN.md §PQ): the LUT block is 3·bm·m·2^nbits·2 B — 6 MiB
+at the defaults (bm=256, m=16, nbits=8), double-buffered — plus a transient
+one-hot [m·2^nbits, min(cell_cap, ADC_CHUNK)] bf16 (at most 2 MiB: a wider
+cell is scored in ADC_CHUNK-column pieces), its [3·bm, ADC_CHUNK] float32
+product and the K-buffers; inside the 64 MiB scoped limit, and the LUT block
+is revisited (not re-DMA'd) across the probe axis since its index map
+ignores j.
 
 Probe-list machinery is inherited verbatim from ``ivf_scan.py``: the
 per-query-tile union list rides in as a scalar-prefetch operand
@@ -43,38 +53,78 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import topk as T
-from repro.core.distances import EXACT, get_distance, matmul_finalize
+from repro.core.distances import get_distance, matmul_finalize
 from repro.kernels._backend import VMEM_LIMIT, resolve_interpret
 from repro.kernels.stream_topk import emit_running, fold_tile, init_running
 
 
-ADC_CHUNK = 256  # code columns per one-hot contraction in the kernel
+ADC_CHUNK = 256  # code columns per one-hot contraction
+LUT_PIECES = 3  # bf16 pieces that hold a float32 LUT entry exactly
+
+# Bits of a float32 that a bf16 keeps: sign, exponent, 7 stored significand.
+_BF16_BITS = np.uint32(0xFFFF0000)
 
 
-def adc_tile(lut_flat, codes_t, ncodes):
+def _bf16_head(x):
+    """The leading bf16 of float32 ``x``, as a float32: ``x`` truncated to 8
+    significand bits.  A bit mask, so no compiler may fold it away."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32) & _BF16_BITS
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def split_lut(lut_flat):
+    """Exact three-way bf16 split of the float32 LUTs.
+
+    ``lut_flat`` [rows, L] float32.  Returns [3, rows, L] bf16: the hi, mid
+    and lo pieces.  Each piece takes the next 8 significand bits of what the
+    earlier ones left, and float32 has 24, so hi + mid + lo == lut bit for
+    bit for zero and every finite entry of magnitude 2^-103 or more (below
+    that the pieces leave float32's normal range).  ``adc_tile`` contracts
+    a tile's three pieces in one MXU pass.
+    """
+    hi = _bf16_head(lut_flat)
+    rest = lut_flat - hi  # exact: the low 16 significand bits
+    mid = _bf16_head(rest)
+    lo = rest - mid  # exact, at most 8 significand bits
+    return jnp.stack([p.astype(jnp.bfloat16) for p in (hi, mid, lo)])
+
+
+def adc_tile(lut_split, codes_t, ncodes):
     """ADC scores [bm, cap] of one code block: one-hot MXU contraction.
 
-    ``lut_flat`` [bm, m·ncodes] fp32 (the flattened per-query LUTs);
-    ``codes_t`` [m, cap] uint8.  Shared verbatim by the Pallas kernel and the
-    jnp reference path (``core.knn.quantized_scan``) so the two scores are
-    bit-identical under the interpreter: same one-hot construction, same
-    ``dot_general`` contraction, same operand shapes when the reference is
-    tiled at tile_n = cell_cap.
+    ``lut_split`` [3, bm, m·ncodes] bf16: one query tile's LUT pieces
+    (``split_lut``); ``codes_t`` [m, cap] uint8.  Either may be a Pallas
+    ref.  The block is scored ADC_CHUNK columns at a time: a bf16 one-hot
+    [m·ncodes, chunk] (0 and 1 are exact) against all three pieces in one
+    default-precision ``dot_general`` with float32 accumulation, then
+    hi + (mid + lo).  Shared verbatim by the Pallas kernel and the jnp
+    reference path (``core.knn.quantized_scan``) so the two scores are
+    bit-identical under the interpreter: same split, same one-hot, same
+    chunks, same contraction when the reference is tiled at tile_n =
+    cell_cap.
     """
+    _, bm, L = lut_split.shape
+    lut = lut_split[...].reshape(LUT_PIECES * bm, L)
     m, cap = codes_t.shape
-    iot = jax.lax.broadcasted_iota(jnp.int32, (m, ncodes, cap), 1)
-    oh = (codes_t.astype(jnp.int32)[:, None, :] == iot).astype(jnp.float32)
-    return jax.lax.dot_general(
-        lut_flat,
-        oh.reshape(m * ncodes, cap),
-        (((1,), (0,)), ((), ())),
-        precision=EXACT,
-        preferred_element_type=jnp.float32,
-    )
+    parts = []
+    for c in range(0, cap, ADC_CHUNK):
+        w = min(ADC_CHUNK, cap - c)
+        ct = codes_t[:, c:c + w].astype(jnp.int32)
+        iot = jax.lax.broadcasted_iota(jnp.int32, (m, ncodes, w), 1)
+        oh = (ct[:, None, :] == iot).astype(jnp.bfloat16)
+        p = jax.lax.dot_general(
+            lut,
+            oh.reshape(m * ncodes, w),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        parts.append(p[:bm] + (p[bm:2 * bm] + p[2 * bm:]))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 def _kernel(K, W, m, ncodes, cell_cap, finalize, threshold_skip, residual):
@@ -95,12 +145,7 @@ def _kernel(K, W, m, ncodes, cell_cap, finalize, threshold_skip, residual):
 
         pl.when(j == 0)(lambda: init_running(run_v, run_i))
 
-        # The one-hot operand is m·2^nbits x chunk fp32 (4 MiB at m = 16,
-        # nbits = 8): wider cells score chunk by chunk to stay in VMEM.
-        chunk = min(cell_cap, ADC_CHUNK)
-        t = jnp.concatenate(
-            [adc_tile(lut_ref[...], codes_ref[:, c:c + chunk], ncodes)
-             for c in range(0, cell_cap, chunk)], axis=1)
+        t = adc_tile(lut_ref, codes_ref, ncodes)
         if residual:
             # alpha·fx·centroid[cell]: the cell's lane of its 128-lane block;
             # a sum of one value and zeros, so the column is exact.
@@ -157,8 +202,9 @@ def pq_scan_pallas(
     """Cell-probed ADC scan over prebuilt LUT operands.
 
     ``probes`` [m/bm, W] int32 per-query-tile cell lists
-    (``core.ivf.tile_probe_lists``); ``luts`` [m, mj·ncodes] fp32 flattened
-    per-query tables (``core.pq.build_pq_luts`` reshaped); ``codes_t``
+    (``core.ivf.tile_probe_lists``); ``luts`` [3, m, mj·ncodes] bf16: the
+    flattened per-query tables (``core.pq.build_pq_luts`` reshaped), split
+    by ``split_lut``; ``codes_t``
     [mj, S] uint8 TRANSPOSED cell-packed codes (S = ncells · cell_cap on the
     lane axis — the streamed operand wants the long axis last); ``hx`` [m, 1]
     / ``hy`` [1, S] rank-1 terms, ``hy`` pre-set to +inf on dead slots;
@@ -174,9 +220,11 @@ def pq_scan_pallas(
     dist = get_distance(distance)
     assert dist.matmul_form is not None, f"{distance} has no MXU form"
     assert codes_t.dtype == jnp.uint8, codes_t.dtype
-    m = luts.shape[0]
+    assert luts.dtype == jnp.bfloat16, luts.dtype
+    _, m, L = luts.shape
     mj, S = codes_t.shape
-    assert luts.shape[1] == mj * ncodes, (luts.shape, mj, ncodes)
+    assert luts.shape[0] == LUT_PIECES and L == mj * ncodes, (
+        luts.shape, mj, ncodes)
     nt, W = probes.shape
     K = T.kernel_k(k)
     Wr = T.reduce_width(cell_cap, K)
@@ -187,7 +235,7 @@ def pq_scan_pallas(
     grid = (m // bm, W)
     residual = qc is not None
     in_specs = [
-        pl.BlockSpec((bm, mj * ncodes), lambda i, j, pr: (i, 0)),
+        pl.BlockSpec((LUT_PIECES, bm, L), lambda i, j, pr: (0, i, 0)),
         pl.BlockSpec((mj, cell_cap), lambda i, j, pr: (0, pr[i, j])),
     ]
     operands = [luts, codes_t]

@@ -54,7 +54,8 @@ def _shapes(sharding, *specs):
     return [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in specs]
 
 
-F32, I8, U8, I32, B = jnp.float32, jnp.int8, jnp.uint8, jnp.int32, jnp.bool_
+F32, BF16, I8, U8, I32, B = (jnp.float32, jnp.bfloat16, jnp.int8, jnp.uint8,
+                             jnp.int32, jnp.bool_)
 
 
 @pytest.mark.parametrize("k,d,gy", [(10, D, F32), (10, D, I8), (100, 256, F32)],
@@ -86,7 +87,8 @@ def test_pq_scan_compiles(one_chip):
         p, lut, codes, hx, hy, 10, cell_cap=CAP, ncodes=ncodes, qc=qc,
         cells=cells, interpret=False)
     _compile(fn, *_shapes(one_chip, ((1, CELLS), I32),
-                          ((M, pq_m * ncodes), F32), ((pq_m, CELLS * CAP), U8),
+                          ((3, M, pq_m * ncodes), BF16),
+                          ((pq_m, CELLS * CAP), U8),
                           ((M, 1), F32), ((1, CELLS * CAP), F32),
                           ((M, CELLS), F32), ((M, 16), I32)))
 

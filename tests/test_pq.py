@@ -211,21 +211,26 @@ def test_ivfpq_query_respects_tombstones(impl):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("residual", [True, False])
-def test_pq_scan_kernel_bit_identical_to_jnp_reference(residual):
+# 8 cells pack 900 rows into cell_cap 256 (= ADC_CHUNK, one chunk); 2 cells
+# into 1024, so ``adc_tile``'s chunk loop runs four times a cell.
+@pytest.mark.parametrize("residual,nlist", [(True, 8), (False, 8), (True, 2)],
+                         ids=["True", "False", "wide_cell"])
+def test_pq_scan_kernel_bit_identical_to_jnp_reference(residual, nlist):
     """The Pallas ADC kernel (interpreter) and the ``quantized_scan`` jnp
     reference share ``adc_tile`` and the LUT builder; tiled identically
     (tile_n = cell_cap, same merge order) they are BIT-identical — values
     and packed-slot indices (acceptance criterion)."""
     from repro.kernels import ops as kops
+    from repro.kernels.pq_scan import ADC_CHUNK
 
     rng = np.random.default_rng(9)
     n, d, m, k = 900, 32, 8, 16
     x = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
     q = jnp.asarray(rng.standard_normal((128, d)).astype(np.float32))
-    ivf = build_ivf(x, 8, iters=5)
+    ivf = build_ivf(x, nlist, iters=5)
     cb, codes = build_ivfpq(x, ivf, m, iters=5, residual=residual)
     cap, ncells = ivf.cell_cap, ivf.ncells
+    assert (cap > ADC_CHUNK) == (nlist == 2), cap
     lp = packed_live(ivf)
     cells = probe_cells(q, ivf.centroids, ncells)  # probe everything
     got = kops.pq_scan(q, cb, codes, cells, k, cell_cap=cap,
@@ -239,6 +244,66 @@ def test_pq_scan_kernel_bit_identical_to_jnp_reference(residual):
                                   np.asarray(want.distances))
     np.testing.assert_array_equal(np.asarray(got.indices),
                                   np.asarray(want.indices))
+
+
+def test_split_lut_rebuilds_the_float32_lut_bit_for_bit():
+    """hi + mid + lo of ``split_lut`` is the float32 LUT, bit for bit, over
+    zeros, both signs and magnitudes from 1e-30 to 1e30; each piece is a
+    bf16 and the pieces stack on a leading axis of 3."""
+    from repro.kernels.pq_scan import split_lut
+
+    rng = np.random.default_rng(14)
+    rows, L = 64, 512
+    lut = (rng.choice([-1.0, 1.0], (rows, L))
+           * 10.0 ** rng.uniform(-30, 30, (rows, L))).astype(np.float32)
+    lut[rng.random((rows, L)) < 0.05] = 0.0
+    lut[3] = rng.standard_normal(L)  # a row of ordinary LUT magnitudes
+    pieces = np.asarray(split_lut(jnp.asarray(lut)))
+    assert pieces.dtype == jnp.bfloat16 and pieces.shape == (3, rows, L)
+    p = pieces.astype(np.float32)
+    rebuilt = p[0] + p[1] + p[2]
+    np.testing.assert_array_equal(rebuilt.view(np.uint32),
+                                  lut.view(np.uint32))
+
+
+@pytest.mark.parametrize("nbits", [8, 4])
+def test_adc_tile_matches_the_float64_gather_sum(nbits):
+    """One bf16 MXU pass over the split LUT scores at float32 accuracy:
+    within 4 float32 ulps of each row's magnitude (its largest sum of
+    |entries|) of the float64 sum of the looked-up entries, at m = 16, over
+    more than one ADC_CHUNK of columns."""
+    from repro.kernels.pq_scan import ADC_CHUNK, adc_tile, split_lut
+
+    rng = np.random.default_rng(nbits)
+    bm, m, ncodes, cap = 32, 16, 2 ** nbits, 2 * ADC_CHUNK
+    # Subspaces of different scales, as partial dots of real queries have.
+    lut = (rng.standard_normal((bm, m, ncodes))
+           * 10.0 ** rng.uniform(-3, 1.5, (bm, m, 1))).astype(np.float32)
+    codes = rng.integers(0, ncodes, (m, cap)).astype(np.uint8)
+    got = np.asarray(adc_tile(split_lut(jnp.asarray(lut.reshape(bm, -1))),
+                              jnp.asarray(codes), ncodes))
+    looked_up = lut[:, np.arange(m)[:, None], codes].astype(np.float64)
+    want = looked_up.sum(axis=1)  # [bm, cap]
+    mag = np.abs(looked_up).sum(axis=1).max(axis=1, keepdims=True)
+    ulp = np.spacing(mag.astype(np.float32)).astype(np.float64)
+    assert got.shape == (bm, cap)
+    assert (np.abs(got - want) <= 4 * ulp).all(), (
+        np.abs(got - want) / ulp).max()
+
+
+def test_chip_smoke_adc_check_runs_in_the_interpreter():
+    """``chip_smoke.check_adc_exact``, the on-chip twin of the test above,
+    passes under the interpreter at a small tile: its Pallas wrapper, its
+    bound, and its refusal of a one-piece bf16 LUT stay in working order."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parent.parent / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    assert smoke.check_adc_exact(bm=16, cap=256, ncells=2,
+                                 interpret=True) <= 4
 
 
 # ---------------------------------------------------------------------------
